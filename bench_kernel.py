@@ -35,7 +35,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
 import time
 
@@ -64,11 +63,6 @@ def main() -> int:
     def log(msg: str) -> None:
         if args.verbose:
             print(msg, file=sys.stderr)
-
-    if os.environ.get("JAX_PLATFORMS"):
-        import jax
-
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
 
     import jax
     import numpy as np

@@ -332,11 +332,6 @@ def main(argv=None) -> int:
     def log(msg):
         print(msg, file=sys.stderr)
 
-    if os.environ.get("JAX_PLATFORMS"):
-        import jax
-
-        jax.config.update("jax_platforms",
-                          os.environ["JAX_PLATFORMS"])
     import tempfile
 
     from cilium_tpu.runtime.provenance import stamp

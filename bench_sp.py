@@ -13,15 +13,13 @@ tiny and L is large.
 
 Prints one JSON line per (S, L) shape:
   {"metric": "sp_vs_seq_S{S}_L{L}", "value": speedup, ...}
-value > 1 means SP is faster. Run on the bench accelerator; the
-crossover (or absence of one) is recorded in docs/PLATFORM.md.
+value > 1 means SP is faster. Run on the bench accelerator.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
 import time
 
@@ -35,10 +33,6 @@ def main() -> int:
     ap.add_argument("--block", type=int, default=256)
     args = ap.parse_args()
 
-    if os.environ.get("JAX_PLATFORMS"):
-        import jax
-
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     import jax
     import jax.numpy as jnp
     import numpy as np

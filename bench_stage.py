@@ -45,11 +45,6 @@ def main() -> int:
         if args.verbose:
             print(msg, file=sys.stderr)
 
-    if os.environ.get("JAX_PLATFORMS"):
-        import jax
-
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
     from cilium_tpu.core.config import Config
     from cilium_tpu.engine.verdict import CaptureReplay
     from cilium_tpu.ingest import binary, synth

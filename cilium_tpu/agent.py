@@ -691,7 +691,7 @@ class Agent:
             raise RuntimeError(
                 "no policy staged — add an endpoint or policy first")
         # one device→host readback, shared by monitor + annotate
-        # (readbacks are the expensive sync point, docs/PLATFORM.md)
+        # (readbacks are the expensive sync point)
         outputs = {
             k: np.asarray(v)
             for k, v in engine.verdict_flows(

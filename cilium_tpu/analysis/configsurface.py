@@ -39,9 +39,9 @@ CONFIG_MODULE = "cilium_tpu.core.config"
 ENV_PREFIX = "CILIUM_TPU_"
 #: doc surfaces scanned for mentions (repo-relative)
 DOC_SOURCES = ("docs", "README.md")
-#: env vars owned by the bench/watch tooling, not the daemon config
+#: env vars owned by the bench tooling, not the daemon config
 #: surface — they live in bench scripts outside the package
-_ENV_EXEMPT_PREFIXES = ("CILIUM_TPU_BENCH_", "CILIUM_TPU_WATCH_")
+_ENV_EXEMPT_PREFIXES = ("CILIUM_TPU_BENCH_",)
 
 _ENV_RE = re.compile(r"\b%s[A-Z0-9_]+\b" % ENV_PREFIX)
 

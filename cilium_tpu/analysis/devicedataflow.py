@@ -55,7 +55,7 @@ RULE_ORDER = "readback-ordering"
 #: the serving hot path lives here; everything else is staging/CLI
 #: surface where a sync is fine. dst.py is the simulation harness
 #: (its reference lane reads back eagerly BY DESIGN, to compare), and
-#: parallel/ is the multi-host compat shim — both out of scope.
+#: parallel/ holds the mesh lanes — both out of scope.
 _SCOPE_PREFIXES = ("cilium_tpu/engine/", "cilium_tpu/runtime/")
 _SCOPE_FILES = ("cilium_tpu/fqdn/dnsproxy.py",)
 _SCOPE_EXCLUDE = ("cilium_tpu/runtime/dst.py",)

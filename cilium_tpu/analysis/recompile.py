@@ -42,7 +42,7 @@ RULE = "recompile-hazard"
 _WRAP_CALLS = {
     "jax.jit", "jit", "jax.pmap", "jax.shard_map", "shard_map",
     "jax.experimental.shard_map.shard_map", "pl.pallas_call",
-    "pallas_call", "cilium_tpu.parallel.compat.shard_map",
+    "pallas_call",
 }
 
 

@@ -40,25 +40,28 @@ def resolve_impl(env=None) -> str:
     (never under trace: flipping the env between traces would
     otherwise be an invisible recompile lever).
 
-    Honest TPU numbers (measured in a clean process with distinct
-    host-staged input buffers and zero device→host readbacks — earlier
-    "gather is 45M/s" numbers were an artifact of benchmark processes
-    poisoned by readbacks, see docs/PLATFORM.md):
+    The arms (none has been timed on a locally attached chip yet —
+    PERF.md):
 
-    * "gather" — one transition-table lookup per (flow, byte, bank);
-      XLA lowers it well on this TPU: ~150G lookups/s at banked-scan
-      shapes. Algorithmically minimal work — the default everywhere.
+    * "gather" — one transition-table lookup per (flow, byte, bank).
+      Algorithmically minimal work — the default everywhere.
     * "pallas" — engine/pallas_dfa.py MXU matmul step: data-oblivious
       (RE2-style input-independent timing) but pays K×S MACs per
       lookup; needs ≤128 states/bank. Kept as an option for
       constant-time-guarantee deployments.
     * "onehot" — the matmul formulation in plain XLA (any state
       count); portable reference implementation.
+
+    Pallas kernels compile for the TPU only: on any other backend the
+    "pallas" pick resolves to "gather" (tests that want the Pallas
+    interpreter call the kernel with ``interpret=True`` themselves).
     """
     import os
 
     env = os.environ if env is None else env
     pick = env.get("CILIUM_TPU_DFA_IMPL", "")
+    if pick == "pallas" and jax.default_backend() != "tpu":
+        return "gather"
     if pick in ("gather", "onehot", "pallas"):
         return pick
     return "gather"
@@ -143,7 +146,7 @@ def dfa_finals_banked(
     data: jax.Array,        # [B, L]
     lengths: jax.Array,     # [B]
     impl: Optional[str] = None,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Final DFA states for every (bank, flow) → [NB, B] int32; the
     accept-table reads layer on top (``dfa_scan_banked``)."""
@@ -151,27 +154,19 @@ def dfa_finals_banked(
     if impl == "pallas":
         from cilium_tpu.engine import pallas_dfa
 
-        # ctlint: disable=recompile-hazard  # impl pick per bank shape is a trace-time static choice, by design
-        if pallas_dfa.pallas_supported(trans.shape):
-            if interpret is None:
-                interpret = pallas_dfa.use_interpret()
-            return pallas_dfa.dfa_finals_pallas(
-                trans, byteclass, start, data, lengths,
-                interpret=interpret)
         # pallas is an explicit opt-in for its input-independent
-        # timing guarantee; degrading to the data-dependent gather
-        # must be loud, not silent
-        import warnings
-
-        warnings.warn(
-            f"CILIUM_TPU_DFA_IMPL=pallas requested but a bank has "
-            f"{trans.shape[1]} states (limit "
-            f"{pallas_dfa.MAX_STATES}); falling back to the "
-            f"data-dependent 'gather' path — the constant-time "
-            f"guarantee does NOT hold. Compile with a smaller "
-            f"bank_size to keep it.",
-            RuntimeWarning, stacklevel=2)
-        impl = "gather"
+        # timing guarantee: a bank it cannot hold is an error, never a
+        # silent swap to the data-dependent gather
+        # ctlint: disable=recompile-hazard  # bank-shape check is a trace-time static, by design
+        if not pallas_dfa.pallas_supported(trans.shape):
+            raise ValueError(
+                f"CILIUM_TPU_DFA_IMPL=pallas requested but a bank has "
+                f"{trans.shape[1]} states (limit "
+                f"{pallas_dfa.MAX_STATES}); compile with a smaller "
+                f"bank_size or drop the pallas pick")
+        return pallas_dfa.dfa_finals_pallas(
+            trans, byteclass, start, data, lengths,
+            interpret=interpret)
     return jax.vmap(
         lambda tr, bc, st: dfa_scan(tr, bc, st, data, lengths, impl=impl)
     )(trans, byteclass, start)              # [NB, B]
@@ -185,17 +180,18 @@ def dfa_scan_banked(
     data: jax.Array,        # [B, L]
     lengths: jax.Array,     # [B]
     impl: Optional[str] = None,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
     extra_accept: Optional[jax.Array] = None,
 ):
     """All banks over one batch → accept words ``[B, NB, W]`` uint32.
 
     ``impl``/``interpret`` are trace-static (resolve on the host via
-    :func:`resolve_impl`; None = "gather" / backend-probe fallback for
-    direct callers). ``extra_accept`` ([NB, S, Wg]) reads a second
-    accept plane off the same final states — the megakernel's
-    group-accept tables (one extra gather, no second scan) — and makes
-    the return a ``(words, extra_words)`` tuple."""
+    :func:`resolve_impl`; None = "gather"; ``interpret`` runs a Pallas
+    pick in the interpreter, for CPU tests). ``extra_accept``
+    ([NB, S, Wg]) reads a second accept plane off the same final
+    states — the megakernel's group-accept tables (one extra gather,
+    no second scan) — and makes the return a ``(words, extra_words)``
+    tuple."""
     impl = impl or "gather"
     finals = dfa_finals_banked(trans, byteclass, start, data, lengths,
                                impl=impl, interpret=interpret)

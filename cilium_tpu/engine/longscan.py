@@ -171,7 +171,7 @@ def _cp_step(mesh: Mesh, seq_axis: str, block: int):
             states, seq_axis, site="cp.final_gather")   # [n_dev, B]
         return all_states[n_dev - 1]
 
-    from cilium_tpu.parallel.compat import shard_map
+    from jax import shard_map
 
     return shard_map(
         local, mesh=mesh,

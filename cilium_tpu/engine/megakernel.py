@@ -791,7 +791,7 @@ def _field_widths(cfg) -> Dict[str, int]:
             "l7g": getattr(cfg, "l7g_len", 256)}
 
 
-def plan_for_engine(policy, cfg, interpret: bool) -> Tuple[
+def plan_for_engine(policy, cfg) -> Tuple[
         Dict[str, str], Dict[str, np.ndarray], Dict[str, Dict]]:
     """Pick a scan impl per field stack; build the NFA tensors the
     picks need. Returns ``(impl_plan, extra_arrays, report)`` —
@@ -845,7 +845,7 @@ def plan_for_engine(policy, cfg, interpret: bool) -> Tuple[
         elif mode == "autotune":
             pick = autotune_field(prefix, policy.arrays, prefix,
                                   nfa_stacked, widths[prefix],
-                                  interpret)
+                                  interpret=False)
         elif mode == "auto" and jax.default_backend() == "tpu" \
                 and not dense_pallas_ok and nfa_stacked is not None:
             # the one regime where the heuristic prefers the NFA arm

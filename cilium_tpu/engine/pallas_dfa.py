@@ -4,10 +4,9 @@ Why a hand-written kernel: the MXU matmul step's cost is shape-only —
 it gives the RE2-style linear-time, *input-independent* timing
 guarantee the reference relies on (SURVEY.md §2.2), which matters for
 deployments where verdict latency must not leak rule or payload
-structure. It is NOT the throughput path: honest clean-process timing
-(docs/PLATFORM.md) shows XLA's native gather sustains ~150G
-transitions/s at banked-scan shapes, so "gather" is the default and
-this kernel is opt-in via CILIUM_TPU_DFA_IMPL=pallas.
+structure. It is NOT the default throughput path: it pays K×S MACs
+per lookup where "gather" pays one lookup, so "gather" is the default
+and this kernel is opt-in via CILIUM_TPU_DFA_IMPL=pallas.
 
 Layout: flows ride the lane axis (TILE=1024 lanes), the state axis
 rides sublanes, and each step is
@@ -136,8 +135,3 @@ def dfa_finals_pallas(
 def pallas_supported(trans_shape) -> bool:
     """True when the banked table fits the kernel's state budget."""
     return trans_shape[1] <= MAX_STATES
-
-
-def use_interpret() -> bool:
-    """Interpret mode off-TPU (CPU tests exercise kernel semantics)."""
-    return jax.default_backend() != "tpu"

@@ -31,6 +31,8 @@ likewise pairs its C event layout with a Go reader.
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import hashlib
 import os
 import subprocess
 import threading
@@ -98,33 +100,54 @@ assert L7REC.itemsize == 32
 _lib_lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _lib_tried = False
+#: True once this process rebuilt the native codec from its sources
+native_built_this_run = False
+
+
+def _sources_digest() -> str:
+    """sha256 over the codec's sources — what the built library is
+    keyed on (mtimes lie after a copy or a checkout)."""
+    h = hashlib.sha256()
+    for n in ("capture.cpp", "Makefile"):
+        with open(os.path.join(NATIVE_DIR, n), "rb") as f:
+            h.update(n.encode() + b"\0" + f.read())
+    return h.hexdigest()
 
 
 def _native() -> Optional[ctypes.CDLL]:
     """The native codec, built on demand; None if unbuildable."""
-    global _lib, _lib_tried
+    global _lib, _lib_tried, native_built_this_run
     with _lib_lock:
         if _lib is not None or _lib_tried:
             return _lib
         _lib_tried = True
-        # rebuild when missing OR older than its sources (a stale
+        # rebuild when missing OR built from other sources (a stale
         # pre-v3 library would reject version-3 files the Python
-        # writer just produced); a current .so costs two stat()s, not
-        # a make fork, per process
-        srcs = [os.path.join(NATIVE_DIR, n)
-                for n in ("capture.cpp", "Makefile")]
+        # writer just produced): the library's stamp file holds the
+        # digest of the sources it was built from
+        stamp = LIB_PATH + ".sha256"
+        # the file lock keeps concurrent processes (test workers) from
+        # loading a library another one is halfway through writing
         try:
-            stale = (not os.path.exists(LIB_PATH)
-                     or os.path.getmtime(LIB_PATH)
-                     < max(os.path.getmtime(s) for s in srcs))
+            lock = open(LIB_PATH + ".lock", "w")
         except OSError:
-            stale = True
-        if stale:
+            return None
+        with lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)
             try:
-                subprocess.run(["make", "-C", NATIVE_DIR],
-                               check=True, capture_output=True)
-            except (OSError, subprocess.CalledProcessError):
-                if not os.path.exists(LIB_PATH):
+                with open(stamp) as f:
+                    stale = (f.read().strip() != _sources_digest()
+                             or not os.path.exists(LIB_PATH))
+            except OSError:
+                stale = True
+            if stale:
+                try:
+                    subprocess.run(["make", "-B", "-C", NATIVE_DIR],
+                                   check=True, capture_output=True)
+                    with open(stamp, "w") as f:
+                        f.write(_sources_digest())
+                    native_built_this_run = True
+                except (OSError, subprocess.CalledProcessError):
                     return None
         try:
             lib = ctypes.CDLL(LIB_PATH)

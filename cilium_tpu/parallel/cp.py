@@ -54,7 +54,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from cilium_tpu.engine.longscan import _compose, block_transitions
 from cilium_tpu.parallel import collectives
-from cilium_tpu.parallel.compat import shard_map
+from jax import shard_map
 
 #: a field only CP-shards when each device gets at least this many
 #: byte columns — below it the exchange would outweigh the scan and

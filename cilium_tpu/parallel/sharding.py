@@ -106,8 +106,7 @@ def make_sharded_step(mesh: Mesh, data_axis: str = "data"):
 
 
 #: values of ``[parallel] lane``: which sharded verdict lane
-#: :func:`stage_for_lane` builds (docs/PLATFORM.md "Multichip
-#: layouts" says which wins when)
+#: :func:`stage_for_lane` builds
 LANES = ("auto", "dp", "ep", "cp")
 
 

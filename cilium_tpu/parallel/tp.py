@@ -45,7 +45,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from cilium_tpu.parallel import collectives
-from cilium_tpu.parallel.compat import shard_map
+from jax import shard_map
 
 #: one-hot matmul carries state ids in f32 — exact only below 2^24
 MAX_TP_STATES = 1 << 24

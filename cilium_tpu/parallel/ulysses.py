@@ -45,7 +45,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from cilium_tpu.engine.dfa_kernel import dfa_scan_banked
 from cilium_tpu.parallel import collectives
-from cilium_tpu.parallel.compat import shard_map
+from jax import shard_map
 
 #: the five scanned string fields: (bank-tensor prefix, batch field)
 _SCAN_FIELDS = (("path", "path"), ("method", "method"),

@@ -57,8 +57,7 @@ the memo/delta/attribution enums).
 
 Adding a protocol is one file: subclass :class:`ProtocolFrontend`,
 declare the spec, call :func:`register_frontend` at import time — see
-``r2d2.py`` in this package for the worked didactic example
-(docs/PLATFORM.md "Protocol frontends" walks through it).
+``r2d2.py`` in this package for the worked didactic example.
 """
 
 from __future__ import annotations

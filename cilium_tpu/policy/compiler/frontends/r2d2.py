@@ -1,8 +1,7 @@
 """r2d2 frontend — the didactic template for writing one.
 
 This file is the whole recipe for putting a proxylib protocol on the
-TPU verdict path (docs/PLATFORM.md "Protocol frontends" walks through
-it line by line):
+TPU verdict path:
 
 1. **Declare the spec.** The ``name`` must match the proxylib
    ``register_parser`` name (one registry — the ``frontend-registry``

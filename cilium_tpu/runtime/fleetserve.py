@@ -989,6 +989,8 @@ class FleetModel:
         dir: host 0 compiles, every later host (and every warm
         rejoin) is satisfied from the content-addressed
         BankArtifactStore — the zero-recompile swap path, measured."""
+        import jax
+
         from cilium_tpu.core.config import Config
         from cilium_tpu.ingest.binary import (
             capture_from_bytes,
@@ -1001,16 +1003,21 @@ class FleetModel:
         self._per_identity = per_identity
         self._cache_dir = tempfile.mkdtemp(prefix="ct_fleet_")
 
-        def mk_loader():
+        # replica i stages on device i (mod the host's device count):
+        # N one-chip replicas behind the router, not N copies on chip 0
+        devices = jax.devices()
+
+        def mk_loader(host_idx: int):
             cfg = Config()
             cfg.enable_tpu_offload = True
             cfg.loader.cache_dir = self._cache_dir
-            loader = Loader(cfg)
+            loader = Loader(cfg,
+                            device=devices[host_idx % len(devices)])
             loader.regenerate(per_identity, revision=1)
             return loader
 
         self._mk_loader = mk_loader
-        loaders = [mk_loader() for _ in range(self.hosts)]
+        loaders = [mk_loader(i) for i in range(self.hosts)]
         engine = loaders[0].engine
         rng = random.Random(self.seed ^ 0x5EED)
         pool: List[_Chunk] = []
@@ -1328,7 +1335,7 @@ class FleetModel:
         name = router.replicas[host_idx].name
         if router.replicas[host_idx].alive:
             return  # suspicion never fired (no-op rejoin)
-        loader = self._mk_loader()
+        loader = self._mk_loader(host_idx)
         bs = loader.bank_status()
         self.rejoin_compiles += bs.get("compiles", 0)
         self.rejoin_artifact_hits += bs.get("artifact_hits", 0)

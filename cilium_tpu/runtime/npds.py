@@ -4,8 +4,8 @@ Reference: the agent pushes per-endpoint NetworkPolicy into Envoy over
 NPDS (``pkg/envoy`` xDS server + the ``cilium.network`` filter, SURVEY
 §2.2/§3.4), so flows with no L7 component verdict IN-PROXY with zero
 agent round-trips. Round 4 inverted that (every verdict crossed the
-service socket), which was fine for bulk replay but put a tunnel RTT
-under every online verdict. This module is the other half: the
+service socket), which was fine for bulk replay but put a service
+round trip under every online verdict. This module is the other half: the
 compiled L3/L4 table serialized into a flat blob the C++ shim
 (``shim/cilium_shim.cpp``) loads and probes locally — only flows whose
 WINNING entry demands L7 inspection or mutual auth still cross the

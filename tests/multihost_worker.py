@@ -32,7 +32,7 @@ def main() -> int:
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from cilium_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from cilium_tpu.parallel.multihost import (
         global_mesh,
         init_multihost,

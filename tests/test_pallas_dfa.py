@@ -1,4 +1,5 @@
-"""Pallas DFA kernel ≡ XLA gather scan (interpret mode on CPU).
+"""Pallas DFA kernel ≡ XLA gather scan (interpret mode on CPU, asked
+for explicitly: the engine never infers it from the backend).
 
 The kernel's contract (engine/pallas_dfa.py): identical final states /
 accept words to the gather path for any bank with ≤128 states.
@@ -34,7 +35,7 @@ def test_pallas_finals_match_gather(nb, s, k, b, l):
     want = dfa_scan_banked(trans, byteclass, start, accept, data, lengths,
                            impl="gather")
     got = dfa_scan_banked(trans, byteclass, start, accept, data, lengths,
-                          impl="pallas")
+                          impl="pallas", interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -55,7 +56,8 @@ def test_pallas_on_compiled_patterns():
     want = dfa_scan_banked(arrs["trans"], arrs["byteclass"], arrs["start"],
                            arrs["accept"], data, lengths, impl="gather")
     got = dfa_scan_banked(arrs["trans"], arrs["byteclass"], arrs["start"],
-                          arrs["accept"], data, lengths, impl="pallas")
+                          arrs["accept"], data, lengths, impl="pallas",
+                          interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -67,13 +69,12 @@ def test_pallas_rejects_oversized_bank():
             np.zeros((4,), np.int32), interpret=True)
 
 
-def test_pallas_fallback_for_large_banks():
-    # banked entry silently falls back to gather when S > 128
+def test_pallas_refuses_large_banks_in_banked_entry():
+    # the banked entry raises when S > 128: an explicit pallas pick is
+    # never silently swapped for the data-dependent gather
     rng = np.random.default_rng(7)
     trans, byteclass, start, accept, data, lengths = _random_banked(
         rng, 2, 200, 6, 16, 8)
-    want = dfa_scan_banked(trans, byteclass, start, accept, data, lengths,
-                           impl="gather")
-    got = dfa_scan_banked(trans, byteclass, start, accept, data, lengths,
-                          impl="pallas")
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    with pytest.raises(ValueError, match="pallas"):
+        dfa_scan_banked(trans, byteclass, start, accept, data, lengths,
+                        impl="pallas", interpret=True)
