@@ -31,7 +31,14 @@ both ways:
   first argument — must appear in the doc's **Fleet event-journal
   catalog** section, both ways: an undocumented kind is an event an
   operator cannot interpret, a catalog row for a kind the journal
-  never records is a stale doc.
+  never records is a stale doc;
+* every **program span** — the literal name of each
+  ``TRACER.span("...")``, ``span("...")`` and ``host_sync("...")``
+  (whose span is ``<site>.wait``) call, and ``capture.stage.<phase>``
+  for each ``_StagePhase("...")`` — must have a row
+  ``cilium_tpu.<name>`` in the doc's **Program span catalog**, both
+  ways: a span without a row is a trace line nobody can read, a row
+  without a span is a stale doc.
 """
 
 from __future__ import annotations
@@ -73,6 +80,17 @@ FLEETSERVE_MODULE = "cilium_tpu.runtime.fleetserve"
 #: the doc section holding the fleet event-journal catalog; rows are
 #: ``| `kind` | ... |`` table lines (same row shape as reasons)
 JOURNAL_SECTION = "## Fleet event-journal catalog"
+
+#: the doc section holding the program span catalog; rows are
+#: ``| `cilium_tpu.<name>` | ... |`` table lines
+SPAN_SECTION = "## Program span catalog"
+_SPAN_ROW_RE = re.compile(r"^\|\s*`cilium_tpu\.([a-z0-9_.-]+)`")
+#: callables whose first literal argument is a span name, by the name
+#: they are called under (``TRACER.span``, the tracing module's
+#: ``span``/``host_sync`` and their underscored import aliases); the
+#: value is the suffix the span name takes
+_SPAN_CALLS = {"span": "", "_span": "", "host_sync": ".wait",
+               "_host_sync": ".wait"}
 
 
 def _declared_families(project: Project) -> Dict[str, Tuple[str, int]]:
@@ -247,7 +265,44 @@ def _journal_kinds(project: Project) -> Dict[str, Tuple[str, int]]:
     return out
 
 
-def _documented_rows(doc_text: str, section: str) -> Dict[str, int]:
+def _has_span_sink(project: Project) -> bool:
+    mi = project.modules.get(TRACING_MODULE)
+    return mi is not None and any(
+        isinstance(node, ast.FunctionDef) and node.name == "span"
+        for node in mi.sf.tree.body)
+
+
+def _span_names(project: Project) -> Dict[str, Tuple[str, int]]:
+    """Every program span name the tree can open → declaring
+    (path, line). A ``.span`` method call counts only on a tracer
+    (``TRACER``/``_TRACER``); bare ``span``/``host_sync`` calls are the
+    tracing module's functions."""
+    out: Dict[str, Tuple[str, int]] = {}
+    for mod in project.modules.values():
+        for node in ast.walk(mod.sf.tree):
+            if not (isinstance(node, ast.Call) and node.args
+                    and isinstance(node.args[0], ast.Constant)
+                    and isinstance(node.args[0].value, str)):
+                continue
+            fn = node.func
+            name = node.args[0].value
+            if isinstance(fn, ast.Attribute):
+                owner = fn.value.id if isinstance(fn.value, ast.Name) \
+                    else ""
+                if fn.attr == "span" and owner.endswith("TRACER"):
+                    out.setdefault(name, (mod.sf.path, node.lineno))
+            elif isinstance(fn, ast.Name):
+                if fn.id in _SPAN_CALLS:
+                    out.setdefault(name + _SPAN_CALLS[fn.id],
+                                   (mod.sf.path, node.lineno))
+                elif fn.id == "_StagePhase":
+                    out.setdefault(f"capture.stage.{name}",
+                                   (mod.sf.path, node.lineno))
+    return out
+
+
+def _documented_rows(doc_text: str, section: str,
+                     row_re=_REASON_ROW_RE) -> Dict[str, int]:
     """Value → doc line of every ``| `value` |`` row under one
     ``## ...`` section."""
     out: Dict[str, int] = {}
@@ -258,7 +313,7 @@ def _documented_rows(doc_text: str, section: str) -> Dict[str, int]:
             continue
         if not in_section:
             continue
-        m = _REASON_ROW_RE.match(line.strip())
+        m = row_re.match(line.strip())
         if m:
             out.setdefault(m.group(1), i)
     return out
@@ -342,6 +397,27 @@ def check_obs_docs(index: ProjectIndex,
                     f"{DOC_PATH} catalogs fleet journal event kind "
                     f"`{kind}` but runtime/fleetserve.py never "
                     f"records it — stale doc or typo"))
+    # program span parity, both directions (only when the tracing
+    # module has the profiler sink, ``def span`` — corpora without it
+    # are not judged)
+    span_names = _span_names(project) if _has_span_sink(project) else {}
+    if span_names:
+        doc_spans = _documented_rows(doc_text, SPAN_SECTION,
+                                     _SPAN_ROW_RE)
+        for name, (path, line) in sorted(span_names.items()):
+            if name not in doc_spans:
+                findings.append(Finding(
+                    path, line, RULE,
+                    f"program span `cilium_tpu.{name}` is not in "
+                    f"{DOC_PATH}'s Program span catalog (a trace line "
+                    f"nobody can read)"))
+        for name, line in sorted(doc_spans.items()):
+            if name not in span_names:
+                findings.append(Finding(
+                    DOC_PATH, line, RULE,
+                    f"{DOC_PATH} catalogs program span "
+                    f"`cilium_tpu.{name}` but nothing in the tree "
+                    f"opens it — stale doc or typo"))
     # stale direction: doc tokens that are no longer declared families
     if families:
         derived = set()

@@ -52,6 +52,7 @@ from cilium_tpu.runtime.metrics import (
     SERVE_PACK_RECORDS,
     SERVE_PACK_STREAMS,
 )
+from cilium_tpu.runtime.tracing import span
 
 #: hard bound on records one pack cycle may carry to the device —
 #: chunks past it wait for the next cycle (pow2-padded shapes above
@@ -205,10 +206,15 @@ class VerdictRing:
             # encode under the session lock: encode may reset the
             # session or consume a policy delta, and pack's dispatch
             # reads the same tables outside the ring lock
-            with self._session_lock:
-                idx, novel = self.session.encode_ids(rec, l7, offsets,
-                                                     blob, gen)
+            with span("ring.session_lock.wait"):
+                self._session_lock.acquire()
+            try:
+                with span("ring.encode"):
+                    idx, novel = self.session.encode_ids(
+                        rec, l7, offsets, blob, gen)
                 epoch = self.session.resets
+            finally:
+                self._session_lock.release()
             known = n - novel
             row_bytes = self.session.row_width * 4
             # selective-copy accounting: known rows ship a 4-byte id

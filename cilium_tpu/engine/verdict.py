@@ -1930,8 +1930,6 @@ def _bools_to_words(bools: jax.Array, n_words: int) -> jax.Array:
 
 import time as _time
 
-from cilium_tpu.runtime import simclock as _simclock
-
 from cilium_tpu.runtime import faults as _faults
 from cilium_tpu.runtime.metrics import (
     CAPTURE_STAGE_SECONDS as _CAPTURE_STAGE_SECONDS,
@@ -1941,6 +1939,8 @@ from cilium_tpu.runtime.tracing import (
     PHASE_DEVICE as _PH_DEVICE,
     PHASE_HOST as _PH_HOST,
     TRACER as _TRACER,
+    host_sync as _host_sync,
+    span as _span,
 )
 
 #: fires at every device dispatch of the jitted engine (the oracle is
@@ -1951,27 +1951,27 @@ DISPATCH_POINT = _faults.register_point(
 
 class _StagePhase:
     """Capture-staging phase timer (perf ledger): seconds into
-    ``cilium_tpu_capture_stage_seconds{phase=...}`` plus a tracer span
-    when a trace is active — benches read ``histo_sum`` deltas to put
-    a machine-readable split next to ``stage_ms``."""
+    ``cilium_tpu_capture_stage_seconds{phase=...}`` (benches read
+    ``histo_sum`` deltas) inside the span ``capture.stage.<phase>``
+    (the profiler always, the recorder when a trace is active)."""
 
-    __slots__ = ("phase", "_t0")
+    __slots__ = ("phase", "_t0", "_span")
 
     def __init__(self, phase: str):
         self.phase = phase
 
     def __enter__(self) -> "_StagePhase":
+        self._span = _TRACER.span(f"capture.stage.{self.phase}",
+                                  phase=_PH_HOST)
+        self._span.__enter__()
         self._t0 = _time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
-        dur = _time.perf_counter() - self._t0
-        _METRICS.observe(_CAPTURE_STAGE_SECONDS, dur,
+        _METRICS.observe(_CAPTURE_STAGE_SECONDS,
+                         _time.perf_counter() - self._t0,
                          labels={"phase": self.phase})
-        ctx = _TRACER.current()
-        if ctx is not None:
-            _TRACER.add_span(ctx, f"capture.stage.{self.phase}",
-                             _PH_HOST, _simclock.wall() - dur, dur)
+        self._span.__exit__(*exc)
 
 
 class VerdictEngine:
@@ -2443,7 +2443,8 @@ class CaptureReplay:
             with _StagePhase("table-h2d"):
                 self.unique_rows = jax.device_put(self._uniq_host,
                                                   self.engine.device)
-                np.asarray(self.unique_rows[:2])  # completion-forced
+                with _host_sync("capture.table_h2d"):
+                    np.asarray(self.unique_rows[:2])  # completion-forced
         return self.unique_rows
 
     # -- verdict memo -----------------------------------------------------
@@ -2557,6 +2558,15 @@ class CaptureReplay:
         the NEXT chunk's id H2D is issued before this one's outputs
         are read back — sequential callers get double-buffered
         transfers for free."""
+        with _span("capture.verdict"):
+            out = self._dispatch_chunk(rec, l7, authed_pairs, start)
+            with _host_sync("capture.readback"):
+                return jax.device_get(out)
+
+    def _dispatch_chunk(self, rec, l7, authed_pairs, start: int
+                        ) -> Dict[str, jax.Array]:
+        """:meth:`verdict_chunk` up to the readback: the chunk's
+        device outputs."""
         self._ensure_current()
         n = len(rec)
         if self.row_idx is not None and self.rows_all is not None:
@@ -2570,7 +2580,7 @@ class CaptureReplay:
             nxt = self.row_idx[start + n:start + 2 * n]
             if len(nxt):
                 self.prefetch_idx(nxt, start + n)
-            return jax.device_get(out)
+            return out
         if self.rows_all is not None:
             rows = self.rows_all[start:start + n]
             if len(rows) != n:
@@ -2582,8 +2592,7 @@ class CaptureReplay:
             gen_rows = (self.feat.gen_rows[start:start + n]
                         if self.feat.gen_rows is not None else None)
             rows = self.feat.encode_rows(rec, l7, gen_rows=gen_rows)
-        out = self.verdict_rows(rows, authed_pairs)
-        return jax.device_get(out)
+        return self.verdict_rows(rows, authed_pairs)
 
 
 def flowbatch_to_host_dict(fb: FlowBatch) -> Dict[str, np.ndarray]:

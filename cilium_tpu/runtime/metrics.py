@@ -188,9 +188,6 @@ SERVE_LATENCY = "cilium_tpu_serve_latency_seconds"
 #: wall seconds one pack cycle spent in the fused dispatch (encode
 #: excluded — submit-side host work is attributed to the submitter)
 SERVE_PACK_DISPATCH_SECONDS = "cilium_tpu_serve_pack_dispatch_seconds"
-#: leased-slot occupancy sampled once per pack cycle (the histogram
-#: face of the occupancy gauge: burn-rate math wants distributions)
-SERVE_PACK_OCCUPANCY = "cilium_tpu_serve_pack_occupancy"
 
 # -- verdict provenance & SLO telemetry (engine/attribution.py,
 # runtime/explain.py, runtime/slo.py)
@@ -728,8 +725,9 @@ METRICS.describe(ENGINE_PHASE_SECONDS,
                           0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
                           1.0, 2.5))
 METRICS.describe(ENGINE_HOST_SYNCS,
-                 "intentional host-device sync points executed, by "
-                 "site (phase-probe completion forcing)")
+                 "blocking device-to-host reads executed, by site "
+                 "(served and replay readbacks, phase-probe "
+                 "completion forcing)")
 METRICS.describe(CAPTURE_STAGE_SECONDS,
                  "capture-replay session staging seconds, by phase",
                  buckets=(0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0,
@@ -821,9 +819,6 @@ METRICS.describe(SERVE_LATENCY,
                  "(installed-clock seconds)")
 METRICS.describe(SERVE_PACK_DISPATCH_SECONDS,
                  "wall seconds per pack-cycle fused dispatch")
-METRICS.describe(SERVE_PACK_OCCUPANCY,
-                 "leased-slot occupancy sampled per pack cycle",
-                 buckets=SIZE_BUCKETS)
 METRICS.describe(SLO_BURN_RATE,
                  "error-budget burn rate, by slo and trailing window "
                  "(1.0 = spending budget exactly as declared)")

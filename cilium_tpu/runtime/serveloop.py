@@ -75,9 +75,9 @@ from cilium_tpu.runtime.metrics import (
     SERVE_LEASE_GRANTS,
     SERVE_LEASE_RELEASES,
     SERVE_PACK_DISPATCH_SECONDS,
-    SERVE_PACK_OCCUPANCY,
     SERVE_RING_OCCUPANCY,
 )
+from cilium_tpu.runtime.tracing import host_sync, span
 
 LOG = get_logger("serveloop")
 
@@ -590,11 +590,12 @@ class ServeLoop:
         feed the SLO trackers, and record explain entries for traced
         chunks. Returns records served."""
         prov = None
-        if hasattr(dev, "slice"):        # ServedPack (provenance on)
-            prov = dev.host()
-            verdicts = np.asarray(prov.verdict)[:n].astype(np.int32)
-        else:
-            verdicts = np.asarray(dev)[:n].astype(np.int32)
+        with host_sync("serve.readback"):
+            if hasattr(dev, "slice"):    # ServedPack (provenance on)
+                prov = dev.host()
+                verdicts = np.asarray(prov.verdict)[:n].astype(np.int32)
+            else:
+                verdicts = np.asarray(dev)[:n].astype(np.int32)
         ticket.resolve(verdicts, prov=prov)
         lat = max(0.0, simclock.now() - ticket.t_submit)
         METRICS.observe(SERVE_LATENCY, lat, labels=self._host_labels)
@@ -664,17 +665,13 @@ class ServeLoop:
                  if self.authed_pairs_fn is not None else None)
         served = 0
         t0 = simclock.perf()
-        with self._pack_lock:
+        with span("ring.pack"), self._pack_lock:
             results = self.ring.pack(authed_pairs=pairs)
         if results:
-            # per-pack-cycle SLO telemetry: dispatch wall, pack size
-            # (SERVE_PACK_RECORDS rides ring.pack), slot occupancy
+            # per-pack-cycle SLO telemetry: dispatch wall (pack size
+            # and streams ride ring.pack; occupancy is the ring gauge)
             METRICS.observe(SERVE_PACK_DISPATCH_SECONDS,
                             max(0.0, simclock.perf() - t0),
-                            labels=self._host_labels)
-            with self._lock:
-                occ = float(len(self._leases))
-            METRICS.observe(SERVE_PACK_OCCUPANCY, occ,
                             labels=self._host_labels)
         for _slot, n, ticket, dev in results:
             if ticket is None:
@@ -686,7 +683,8 @@ class ServeLoop:
                     self.chunk_errors += 1
                 ticket.resolve(None, error="session-reset")
                 continue
-            served += self._resolve_ticket(ticket, n, dev)
+            with span("serve.resolve"):
+                served += self._resolve_ticket(ticket, n, dev)
         with self._stats_lock:
             self.served_records += served
             if results:
@@ -705,8 +703,10 @@ class ServeLoop:
             # hold the (possibly autojumping) virtual clock while the
             # pack's REAL compute runs: a dispatch/compile must not
             # read as idle time, or simulated latencies would inflate
-            # by wall compute (see simclock.hold)
-            with simclock.hold():
+            # by wall compute (see simclock.hold). Every cycle is a
+            # span, empty ones too: the gaps between their starts are
+            # the pack thread's cadence, stalls included
+            with span("serve.cycle"), simclock.hold():
                 try:
                     self.step()
                 except Exception as e:  # noqa: BLE001 — degrade,
@@ -718,7 +718,8 @@ class ServeLoop:
                                 "interval", extra={"fields": {
                                     "error": f"{type(e).__name__}: "
                                              f"{e}"}})
-            simclock.sleep(self.pack_interval_s)
+            with span("serve.sleep.wait"):
+                simclock.sleep(self.pack_interval_s)
 
     def start(self) -> "ServeLoop":
         """Spawn the production pack thread (virtual-time ready: the
@@ -749,7 +750,7 @@ class ServeLoop:
             pairs = (self.authed_pairs_fn()
                      if self.authed_pairs_fn is not None else None)
             t0 = simclock.perf()
-            with self._pack_lock:
+            with span("ring.pack"), self._pack_lock:
                 results = self.ring.pack(authed_pairs=pairs)
             if not results:
                 break
@@ -761,7 +762,8 @@ class ServeLoop:
                         self.chunk_errors += 1
                     ticket.resolve(None, error="session-reset")
                     continue
-                flushed += self._resolve_ticket(ticket, n, dev)
+                with span("serve.resolve"):
+                    flushed += self._resolve_ticket(ticket, n, dev)
             with self._stats_lock:
                 self.pack_seconds += max(0.0, simclock.perf() - t0)
         with self._stats_lock:

@@ -86,6 +86,7 @@ from cilium_tpu.runtime.tracing import (
     PHASE_QUEUE,
     TRACE_ID_CHARS,
     TRACER,
+    span,
 )
 
 #: fires at the server's per-chunk dispatch (a fault fails ONE seq —
@@ -231,7 +232,8 @@ class StreamSession:
         try:
             while True:
                 try:
-                    seq, kind, payload = recv_frame(self.sock)
+                    with span("stream.recv.wait"):
+                        seq, kind, payload = recv_frame(self.sock)
                 except (ConnectionError, OSError):
                     break
                 # receive stamp: the worker attributes reader-queue
@@ -353,7 +355,7 @@ class StreamSession:
         except ShedError:
             self.serveloop = None
             return None
-        with TRACER.span("stream.ring", phase=PHASE_DEVICE,
+        with TRACER.span("serve.submit", phase=PHASE_HOST,
                          records=len(rec)):
             try:
                 ticket = loop.submit(self._lease, rec, l7, offsets,
@@ -363,6 +365,8 @@ class StreamSession:
                                            resume=True)
                 ticket = loop.submit(self._lease, rec, l7, offsets,
                                      blob, gen=gen)
+        with TRACER.span("serve.ticket.wait", phase=PHASE_DEVICE,
+                         records=len(rec)):
             return ticket.wait(timeout=30.0)
 
     def _oracle_chunk(self, rec, l7, offsets, blob, gen, pairs):
@@ -394,35 +398,37 @@ class StreamSession:
                 self._out.put((seq, KIND_END, 0, None, None))
                 self._out.put(None)
                 return
-            ctx = None
-            if kind == KIND_CHUNK_TRACED:
-                # adopt the client's trace id (the CLIENT sampled;
-                # adoption bypasses the local sampler) and split the
-                # id prefix off the capture image
-                tid = payload[:TRACE_ID_CHARS].decode("ascii", "replace")
-                payload = payload[TRACE_ID_CHARS:]
-                ctx = TRACER.start("stream.chunk", trace_id=tid,
-                                   seq=seq)
-                kind = KIND_CHUNK
-            if kind != KIND_CHUNK:
-                self._out.put((seq, KIND_ERROR, 0,
-                               f"unknown frame kind {kind}", None))
-                continue
-            if ctx is not None:
-                waited = simclock.now() - t_recv
-                TRACER.add_span(ctx, "stream.queue", PHASE_QUEUE,
-                                simclock.wall() - waited, waited)
-            try:
-                with TRACER.activate(ctx):
-                    n, dev = self._dispatch_chunk(payload)
-            except Exception as e:  # noqa: BLE001 — fail the SEQ only
-                TRACER.event("stream.chunk_error", ctx=ctx,
-                             error=f"{type(e).__name__}: {e}")
-                TRACER.finish(ctx)
-                self._out.put((seq, KIND_ERROR, 0,
-                               f"{type(e).__name__}: {e}", None))
-                continue
-            self._out.put((seq, KIND_CHUNK, n, dev, ctx))
+            with span("stream.chunk"):
+                ctx = None
+                if kind == KIND_CHUNK_TRACED:
+                    # adopt the client's trace id (the CLIENT sampled;
+                    # adoption bypasses the local sampler) and split
+                    # the id prefix off the capture image
+                    tid = payload[:TRACE_ID_CHARS].decode("ascii",
+                                                          "replace")
+                    payload = payload[TRACE_ID_CHARS:]
+                    ctx = TRACER.start("stream.chunk", trace_id=tid,
+                                       seq=seq)
+                    kind = KIND_CHUNK
+                if kind != KIND_CHUNK:
+                    self._out.put((seq, KIND_ERROR, 0,
+                                   f"unknown frame kind {kind}", None))
+                    continue
+                if ctx is not None:
+                    waited = simclock.now() - t_recv
+                    TRACER.add_span(ctx, "stream.queue", PHASE_QUEUE,
+                                    simclock.wall() - waited, waited)
+                try:
+                    with TRACER.activate(ctx):
+                        n, dev = self._dispatch_chunk(payload)
+                except Exception as e:  # noqa: BLE001 — fail the SEQ
+                    TRACER.event("stream.chunk_error", ctx=ctx,
+                                 error=f"{type(e).__name__}: {e}")
+                    TRACER.finish(ctx)
+                    self._out.put((seq, KIND_ERROR, 0,
+                                   f"{type(e).__name__}: {e}", None))
+                    continue
+                self._out.put((seq, KIND_CHUNK, n, dev, ctx))
 
     def _grant_credit(self, seq: int) -> None:
         """One credit back to the producer for one answered chunk. An
@@ -470,7 +476,7 @@ class StreamSession:
                                  ctx=ctx, records=n):
                     verdicts = np.asarray(dev)[:n].astype(np.uint8)
                 METRICS.inc("cilium_tpu_stream_verdicts_total", n)
-                with self._send_lock:
+                with span("stream.send"), self._send_lock:
                     send_frame(self.sock, seq, KIND_CHUNK,
                                verdicts.tobytes())
                 # grant AFTER the verdict frame: the window counts

@@ -31,12 +31,28 @@ Three export faces (one id joins all three):
   (``hubble/observer.py``) and on JSONL log lines
   (``runtime/logging.py``), so metrics, flows, and logs correlate.
 
+Every span has a second sink: the JAX profiler. ``TRACER.span(...)``
+and the context-free :func:`span` (code that serves no one request:
+the pack thread, a replay's verdict chunk) open
+``cilium_tpu.<layer>.<what>`` on the calling thread's host line
+whenever a profiler session is on, sampled or not, so a chip trace
+shows which layer the host was in while the device idled. The
+profiler names every Python thread's line ``python``, so each span
+name says whose work it is (``docs/OBSERVABILITY.md``, span catalog),
+and a span that blocks (a lock, a readback, a sleep) ends in
+``.wait``. While a session is on, the spans are also kept, on the
+host's ``perf_counter`` clock, for readers in the same process
+(:func:`profiled_spans`). :func:`host_sync` spans one blocking
+device→host read and counts it on
+``cilium_tpu_engine_host_syncs_total{site}``.
+
 Design constraints, in order:
 
-* **Near-zero cost disarmed.** ``TRACER.span(...)`` with tracing
-  disabled or no active context returns a shared no-op context
-  manager — one attribute read and one contextvar get. Nothing here
-  runs per flow; instrumentation is per request/batch/chunk.
+* **Near-zero cost disarmed.** With no sampled context and no
+  profiler session, a span is one contextvar get, one object and one
+  ``TraceMe.is_enabled()`` call (well under 1 µs). Nothing here runs
+  per flow; instrumentation is per request/batch/chunk. The module
+  never imports jax itself: with jax not loaded, no session is on.
 * **Bounded.** Completed spans land in a ``deque(maxlen=capacity)``;
   a long-running agent's recorder memory is a constant.
 * **Batch-safe.** A MicroBatcher flush serves many requests at once;
@@ -53,13 +69,19 @@ either side are unaffected.
 from __future__ import annotations
 
 import contextvars
+import sys
 import threading
+import time
 import uuid
 from collections import deque
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from cilium_tpu.runtime import simclock
-from cilium_tpu.runtime.metrics import METRICS, TRACE_SPANS
+from cilium_tpu.runtime.metrics import (
+    ENGINE_HOST_SYNCS,
+    METRICS,
+    TRACE_SPANS,
+)
 
 #: canonical phase names (ISSUE 2); free-form phases are allowed but
 #: these four are what the attribution tooling groups by
@@ -140,32 +162,79 @@ class GroupContext:
         return 0
 
 
-class _NoopSpan:
-    """Shared do-nothing context manager (disarmed path)."""
+#: profiler span names are ``SPAN_PREFIX + <layer>.<what>``
+SPAN_PREFIX = "cilium_tpu."
+#: the suffix of a span that blocks (lock, readback, sleep)
+WAIT_SUFFIX = ".wait"
+#: bound on the profiled-span log (an entry holds ~150 bytes)
+PROFILED_CAPACITY = 1 << 19
 
-    __slots__ = ()
+#: ``(name, start_ns, end_ns, thread ident)`` of every span closed
+#: while a profiler session was on, on the ``perf_counter_ns`` clock;
+#: process-global, like the profiler session it mirrors
+_PROFILED: deque = deque(maxlen=PROFILED_CAPACITY)
+#: ``jax.profiler.TraceAnnotation`` once jax is loaded
+_ANNOTATION = None
+
+
+def _annotation():
+    """The profiler's annotation class while a session is on, else
+    None. Looked up in ``sys.modules``: a process that never loaded
+    jax has no session to write to."""
+    global _ANNOTATION
+    cls = _ANNOTATION
+    if cls is None:
+        prof = sys.modules.get("jax.profiler")
+        if prof is None:
+            return None
+        cls = _ANNOTATION = prof.TraceAnnotation
+    return cls if cls.is_enabled() else None
+
+
+class _ProfileSpan:
+    """``cilium_tpu.<name>`` on the profiler's host line of the calling
+    thread, and in the profiled-span log; nothing with no session on.
+    The context-free span (:func:`span`), and the profiler half of
+    every recorder span."""
+
+    __slots__ = ("name", "_tm", "_t0")
+
+    def __init__(self, name: str):
+        self.name = name
 
     def __enter__(self):
+        cls = _annotation()
+        self._tm = None if cls is None else cls(SPAN_PREFIX + self.name)
+        if self._tm is not None:
+            self._tm.__enter__()
+            self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
+        tm = self._tm
+        if tm is not None:
+            t1 = time.perf_counter_ns()
+            tm.__exit__(*exc)
+            _PROFILED.append((SPAN_PREFIX + self.name, self._t0, t1,
+                              threading.get_ident()))
         return False
 
 
-_NOOP = _NoopSpan()
+class _SpanCM(_ProfileSpan):
+    """A recorder span under a sampled context: the profiler span plus
+    one ring record per member trace."""
 
-
-class _SpanCM:
-    __slots__ = ("tracer", "ctx", "name", "phase", "attrs", "t0")
+    __slots__ = ("tracer", "ctx", "phase", "attrs", "t0")
 
     def __init__(self, tracer, ctx, name, phase, attrs):
+        super().__init__(name)
         self.tracer = tracer
         self.ctx = ctx
-        self.name = name
         self.phase = phase
         self.attrs = attrs
 
     def __enter__(self):
+        super().__enter__()
         self.t0 = simclock.wall()
         return self
 
@@ -176,7 +245,32 @@ class _SpanCM:
                               error=f"{exc_type.__name__}: {exc}")
         self.tracer._record(self.ctx, self.name, self.phase,
                             self.t0, dur, self.attrs)
-        return False
+        return super().__exit__(exc_type, exc, tb)
+
+
+def span(name: str) -> _ProfileSpan:
+    """A program span with no request behind it: the profiler and the
+    profiled-span log only, never the recorder ring. ``name`` is
+    ``<layer>.<what>``; a blocking wait ends in ``.wait``."""
+    return _ProfileSpan(name)
+
+
+def host_sync(site: str) -> _ProfileSpan:
+    """Span ``<site>.wait`` around one blocking device→host read, and
+    count it on ``cilium_tpu_engine_host_syncs_total{site}``."""
+    METRICS.inc(ENGINE_HOST_SYNCS, labels={"site": site})
+    return _ProfileSpan(site + WAIT_SUFFIX)
+
+
+def profiled_spans() -> List[Tuple[str, int, int, int]]:
+    """``(name, start_ns, end_ns, thread ident)`` of the spans closed
+    while a profiler session was on, oldest first (``perf_counter_ns``
+    clock; at most :data:`PROFILED_CAPACITY`, the newest kept)."""
+    return list(_PROFILED)
+
+
+def clear_profiled_spans() -> None:
+    _PROFILED.clear()
 
 
 class Tracer:
@@ -273,11 +367,12 @@ class Tracer:
 
     # -- recording --------------------------------------------------------
     def span(self, name: str, phase: str = "", ctx=None, **attrs):
-        """Measured span context manager; no-op when no trace is
-        active (the production disarmed path)."""
+        """Measured span context manager: the profiler span always
+        (:func:`span`), a ring record per member only when a sampled
+        trace is active."""
         ctx = ctx if ctx is not None else _CURRENT.get()
         if ctx is None or not self.enabled:
-            return _NOOP
+            return _ProfileSpan(name)
         return _SpanCM(self, ctx, name, phase, attrs)
 
     def add_span(self, ctx, name: str, phase: str,

@@ -601,3 +601,23 @@ def test_cli_capture_synth_is_reproducible(tmp_path, capsys):
         rec = json.loads(capsys.readouterr().out)
         assert rec["records"] == 200 and rec["version"] == 2
     assert open(a, "rb").read() == open(b, "rb").read()  # same seed
+
+
+def test_replay_chunk_readback_counts_one_host_sync(tmp_path):
+    from cilium_tpu.engine.verdict import CaptureReplay
+    from cilium_tpu.runtime.metrics import ENGINE_HOST_SYNCS, METRICS
+
+    per_identity, scenario = synth.realize_scenario(_scenario("http"))
+    cfg = Config()
+    cfg.enable_tpu_offload = True
+    engine = Loader(cfg).regenerate(per_identity, revision=1)
+    path = str(tmp_path / "cap.bin")
+    binary.write_capture_l7(path, scenario.flows)
+    rec = binary.map_capture(path)
+    l7, offsets, blob = binary.read_l7_sidecar(path)
+    replay = CaptureReplay(engine, l7, offsets, blob, cfg.engine)
+    labels = {"site": "capture.readback"}
+    before = METRICS.get(ENGINE_HOST_SYNCS, labels)
+    for s in range(0, 200, 100):
+        replay.verdict_chunk(rec[s:s + 100], l7[s:s + 100], start=s)
+    assert METRICS.get(ENGINE_HOST_SYNCS, labels) == before + 2

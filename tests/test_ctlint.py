@@ -8,6 +8,8 @@ import os
 import socket
 import threading
 
+import pytest
+
 from cilium_tpu.analysis import run
 from cilium_tpu.analysis.core import ProjectIndex
 from cilium_tpu.analysis import abi as abi_rule
@@ -1322,6 +1324,91 @@ def test_reason_labels_real_tree_nonvacuous():
               encoding="utf-8") as fp:
         documented = obs_rule._documented_reasons(fp.read())
     assert set(values) <= set(documented)
+
+
+# -- obs-doc-parity: program span catalog ------------------------------------
+
+OBS_SPANS = '''\
+def serve(lease):
+    with TRACER.span("serve.submit", phase="host-prep"):
+        pass
+    with span("serve.cycle"):
+        with host_sync("serve.readback"):
+            pass
+    with _StagePhase("tables"):
+        pass
+    with other.span("not.a.program.span"):
+        pass
+'''
+
+SPAN_DOC = OBS_DOC_COMPLETE + (
+    "\n## Program span catalog\n\n"
+    "| span | thread | covers | read by |\n|---|---|---|---|\n"
+    "| `cilium_tpu.serve.submit` | worker | submit | — |\n"
+    "| `cilium_tpu.serve.cycle` | pack | a cycle | gap p99 |\n"
+    "| `cilium_tpu.serve.readback.wait` | pack | readback | syncs |\n"
+    "| `cilium_tpu.capture.stage.tables` | replay | tables | — |\n"
+    "\n## after\n")
+
+
+def _span_sources():
+    tracing = OBS_TRACING + "\n\ndef span(name):\n    return name\n"
+    return {**OBS_SOURCES, "cilium_tpu/runtime/tracing.py": tracing,
+            "cilium_tpu/runtime/serveloop.py": OBS_SPANS}
+
+
+def test_span_catalog_complete_is_clean():
+    assert _check(_span_sources(), obs_rule.check_obs_docs,
+                  doc_text=SPAN_DOC) == []
+
+
+@pytest.mark.parametrize("row,name", [
+    ("| `cilium_tpu.serve.cycle` | pack | a cycle | gap p99 |\n",
+     "cilium_tpu.serve.cycle"),
+    ("| `cilium_tpu.serve.readback.wait` | pack | readback | syncs |\n",
+     "cilium_tpu.serve.readback.wait"),
+    ("| `cilium_tpu.capture.stage.tables` | replay | tables | — |\n",
+     "cilium_tpu.capture.stage.tables"),
+])
+def test_span_catalog_flags_span_without_row(row, name):
+    findings = _check(_span_sources(), obs_rule.check_obs_docs,
+                      doc_text=SPAN_DOC.replace(row, ""))
+    assert len(findings) == 1, [f.message for f in findings]
+    assert f"`{name}`" in findings[0].message
+    assert findings[0].path == "cilium_tpu/runtime/serveloop.py"
+
+
+def test_span_catalog_flags_stale_row():
+    doc = SPAN_DOC.replace(
+        "| `cilium_tpu.serve.submit` | worker | submit | — |",
+        "| `cilium_tpu.serve.submit` | worker | submit | — |\n"
+        "| `cilium_tpu.serve.gone.wait` | pack | retired | — |")
+    findings = _check(_span_sources(), obs_rule.check_obs_docs,
+                      doc_text=doc)
+    assert len(findings) == 1
+    assert "serve.gone.wait" in findings[0].message
+    assert findings[0].path.endswith("OBSERVABILITY.md")
+
+
+def test_span_catalog_real_tree_nonvacuous():
+    """Both hot paths' spans are collected from the shipped tree, and
+    the shipped catalog lists exactly them."""
+    import os
+
+    from cilium_tpu.analysis.callgraph import Project
+
+    index, errors = ProjectIndex.from_tree(REPO_ROOT)
+    assert not errors
+    names = obs_rule._span_names(Project(index))
+    for expected in ("stream.chunk", "serve.ticket.wait", "serve.cycle",
+                     "serve.readback.wait", "capture.readback.wait",
+                     "capture.stage.featurize", "ring.session_lock.wait"):
+        assert expected in names, expected
+    with open(os.path.join(REPO_ROOT, "docs", "OBSERVABILITY.md"),
+              encoding="utf-8") as fp:
+        rows = obs_rule._documented_rows(
+            fp.read(), obs_rule.SPAN_SECTION, obs_rule._SPAN_ROW_RE)
+    assert set(rows) == set(names)
 
 
 def test_obs_doc_parity_real_tree_nonvacuous():

@@ -535,3 +535,25 @@ def test_lifetime_counters_exact_under_concurrent_bumps(tmp_path):
         finally:
             sys.setswitchinterval(old)
         assert loop.sheds == before + 1 + n_threads * per_thread
+
+
+def test_pack_cycle_reads_back_once_per_chunk(tmp_path):
+    """Every packed chunk is resolved by its own blocking readback:
+    three chunks in one pack count three ``serve.readback`` host
+    syncs, however few packs carried them."""
+    from cilium_tpu.runtime.metrics import ENGINE_HOST_SYNCS, METRICS
+
+    labels = {"site": "serve.readback"}
+    clk = VirtualClock()
+    with simclock.use(clk):
+        loop, loader, scenario = _world(tmp_path)
+        flows = scenario.flows[:300]
+        lease = loop.connect("s0")
+        before = METRICS.get(ENGINE_HOST_SYNCS, labels)
+        packs = loop.ring.packs
+        tickets = [loop.submit(lease, *_sections(flows[i:i + 100]))
+                   for i in range(0, 300, 100)]
+        assert loop.step() == 300
+        assert all(t.done and t.error is None for t in tickets)
+        assert loop.ring.packs == packs + 1
+        assert METRICS.get(ENGINE_HOST_SYNCS, labels) == before + 3

@@ -590,3 +590,102 @@ def test_stitch_single_host_single_epoch_is_not_stitched():
     assert out["stitched"] is False
     assert out["hosts"] == ["hA"]
     assert out["epochs"] == [0]
+
+
+# ---------------------------------------------------------------------------
+# The profiler sink: program spans on the host plane
+
+
+def _host_events(trace_dir):
+    """``{name: [(line index, start_ns, end_ns), ...]}`` of the
+    ``cilium_tpu.`` events on the host plane of a saved trace."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for k, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name.startswith("cilium_tpu."):
+                    out.setdefault(e.name, []).append(
+                        (k, int(e.start_ns),
+                         int(e.start_ns + e.duration_ns)))
+    return out
+
+
+def test_program_spans_nest_on_the_profiler_host_plane(tmp_path):
+    """An unsampled recorder span and a context-free ``.wait`` span
+    inside it reach a CPU profiler trace as nested events on one host
+    line, another thread's span on a line of its own, and the
+    profiled-span log holds the same spans; with the profiler off,
+    nothing is logged."""
+    import jax
+
+    from cilium_tpu.runtime import tracing
+
+    def other():
+        with tracing.span("test.other"):
+            time.sleep(0.002)
+
+    tracing.clear_profiled_spans()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with TRACER.span("test.outer", phase=PHASE_HOST):
+            with tracing.span("test.inner.wait"):
+                time.sleep(0.002)
+            t = threading.Thread(target=other)
+            t.start()
+            t.join()
+    finally:
+        jax.profiler.stop_trace()
+    with tracing.span("test.after"):
+        pass
+    ev = _host_events(str(tmp_path))
+    (lo, o0, o1), = ev["cilium_tpu.test.outer"]
+    (li, i0, i1), = ev["cilium_tpu.test.inner.wait"]
+    (lt, _, _), = ev["cilium_tpu.test.other"]
+    assert li == lo and o0 <= i0 < i1 <= o1 and lt != lo
+    logged = tracing.profiled_spans()
+    assert [s[0] for s in logged] == ["cilium_tpu.test.inner.wait",
+                                      "cilium_tpu.test.other",
+                                      "cilium_tpu.test.outer"]
+    inner, other_, outer = logged
+    assert outer[1] <= inner[1] < inner[2] <= outer[2]
+    assert inner[3] == outer[3] != other_[3]
+    assert TRACER.dump() == []  # nothing was sampled
+    tracing.clear_profiled_spans()
+
+
+@pytest.mark.parametrize("sampled", [True, False])
+def test_recorder_ring_fills_only_under_a_sampled_context(sampled):
+    from cilium_tpu.runtime import tracing
+
+    tr = Tracer(sample_rate=1.0 if sampled else 0.0)
+    with tr.span("work", phase=PHASE_HOST):
+        pass  # no context at all
+    assert tr.dump() == []
+    with tr.trace("req") as ctx:
+        assert (ctx is not None) == sampled
+        with tr.span("work", phase=PHASE_HOST):
+            pass
+        with tracing.span("structure.only"):
+            pass  # context-free: profiler only, never the ring
+    names = [r["name"] for r in tr.dump()]
+    assert names == (["work", "req"] if sampled else [])
+
+
+def test_host_sync_counts_and_spans_a_wait():
+    from cilium_tpu.runtime.metrics import ENGINE_HOST_SYNCS
+    from cilium_tpu.runtime.tracing import host_sync
+
+    labels = {"site": "test.read"}
+    before = METRICS.get(ENGINE_HOST_SYNCS, labels)
+    with host_sync("test.read") as s:
+        assert s.name == "test.read.wait"
+    assert METRICS.get(ENGINE_HOST_SYNCS, labels) == before + 1
