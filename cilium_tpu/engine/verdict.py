@@ -1022,6 +1022,11 @@ def _gen_l7g_cols(gen, offsets: np.ndarray, blob: np.ndarray):
     return fam, uniq_serialized, row
 
 
+def _pow2_rows(n: int) -> int:
+    """The power-of-two row bucket that holds ``n`` rows (at least 1)."""
+    return 1 << max(0, (max(1, n) - 1)).bit_length()
+
+
 def _pad_rows_pow2(*arrays):
     """Pad each array's FIRST axis (same length across arrays) with
     zeros up to the next power of two — shape buckets so the jitted
@@ -1029,7 +1034,7 @@ def _pad_rows_pow2(*arrays):
     of compiling per-file exact sizes. Padded rows must never be
     referenced (valid-masked or absent from every id stream)."""
     n = len(arrays[0])
-    S_pad = 1 << max(0, (max(1, n) - 1)).bit_length()
+    S_pad = _pow2_rows(n)
     if S_pad == n:
         return arrays if len(arrays) > 1 else arrays[0]
     out = tuple(
@@ -1043,14 +1048,18 @@ class CaptureFeaturizer:
     """Chunked-replay featurizer over one v2 capture: pays the string
     work ONCE per file, then each chunk is pure row gathers.
 
-    At construction, every string each field references is encoded
-    into a padded per-field table ([S_used, L] u8 + lengths + valid),
-    kafka strings resolve to engine intern ids, and a string-table →
-    row LUT is built per field. ``encode(rec, l7)`` then featurizes a
-    chunk with ~8 numpy row-gathers — this is what lets file→verdict
-    replay keep pace with the device (north star "replaying a Hubble
-    capture"; the reference's per-request parse has no analog of this
-    because its datapath consumes one packet at a time)."""
+    Every string each field references is encoded into a padded
+    per-field table ([S_used, L] u8 + lengths + valid), kafka strings
+    resolve to engine intern ids, and a string-table → row LUT is
+    built per field. Construction builds the LUTs and allocates the
+    tables; their rows are built in order as they are first read
+    (:meth:`table_rows`, :attr:`tables`), so :func:`stage_capture_tables`
+    can scan one row piece on the device while the host builds the
+    next. ``encode(rec, l7)`` then featurizes a chunk with ~8 numpy
+    row-gathers — this is what lets file→verdict replay keep pace with
+    the device (north star "replaying a Hubble capture"; the
+    reference's per-request parse has no analog of this because its
+    datapath consumes one packet at a time)."""
 
     _FIELD_CAPS = (("path", "http_path_buckets"),
                    ("method", "http_method_len"),
@@ -1082,28 +1091,35 @@ class CaptureFeaturizer:
                  l7g_row[:, None].astype(np.int32), gen_block[:, 1:]],
                 axis=1)
         n_strings = len(offsets) - 1
-        self.tables: Dict[str, tuple] = {}
+        self._offsets, self._blob = offsets, blob
+        self._tables: Dict[str, tuple] = {}
+        #: per string field, the string ids of its table rows, and how
+        #: many of those rows are built
+        self._used: Dict[str, np.ndarray] = {}
+        self._built: Dict[str, int] = {}
         self.luts: Dict[str, np.ndarray] = {}
         for field, _ in self._FIELD_CAPS:
             used = np.unique(l7[field])
-            data, lens, valid = _gather_table_field(
-                blob, offsets, used, self.widths[field],
-                fixed_len=self.widths[field])
-            # shape-bucket the string count (_pad_rows_pow2): the
-            # staged table scan (stage_capture_tables) then hits the
-            # persistent XLA cache across captures instead of paying
-            # a fresh compile per shape
-            data, lens, valid = _pad_rows_pow2(data, lens, valid)
+            # shape-bucket the string count (pow2, as _pad_rows_pow2):
+            # the staged table scan (stage_capture_tables) then hits
+            # the persistent XLA cache across captures instead of
+            # paying a fresh compile per shape; the padded rows stay
+            # zero and invalid
+            S_pad, L = _pow2_rows(len(used)), self.widths[field]
+            self._tables[field] = (np.zeros((S_pad, L), dtype=np.uint8),
+                                   np.zeros(S_pad, dtype=np.int32),
+                                   np.zeros(S_pad, dtype=bool))
+            self._used[field] = used
+            self._built[field] = 0
             lut = np.zeros(n_strings, dtype=np.int32)
             lut[used] = np.arange(len(used), dtype=np.int32)
-            self.tables[field] = (data, lens, valid)
             self.luts[field] = lut
         if self._l7g_uniq is not None:
             # frontend record serializations as one more staged string
             # table (scanned through the l7g automaton when the policy
             # carries frontend rules); no LUT — l7g_rows already
             # indexes this table directly
-            self.tables["l7g"] = _pad_rows_pow2(
+            self._tables["l7g"] = _pad_rows_pow2(
                 *encode_strings(self._l7g_uniq, cfg.l7g_len))
         for col, key in (("kafka_client", "client_id"),
                          ("kafka_topic", "topic")):
@@ -1112,6 +1128,38 @@ class CaptureFeaturizer:
             lut = np.full(n_strings, -2, dtype=np.int32)
             lut[used] = ids
             self.luts[col] = lut
+
+    @property
+    def tables(self) -> Dict[str, tuple]:
+        """Each field's whole padded table ``(data [S_pad, L] u8,
+        lengths, valid)``, its unbuilt rows built first."""
+        for field, used in self._used.items():
+            self._build_rows(field, len(used))
+        return self._tables
+
+    def padded_rows(self) -> Dict[str, int]:
+        """Each table's padded row count, building nothing."""
+        return {field: len(t[0]) for field, t in self._tables.items()}
+
+    def table_rows(self, field: str, lo: int, hi: int) -> tuple:
+        """Rows ``[lo, hi)`` of ``field``'s padded table (views), every
+        row before ``hi`` built first."""
+        self._build_rows(field, hi)
+        return tuple(a[lo:hi] for a in self._tables[field])
+
+    def _build_rows(self, field: str, hi: int) -> None:
+        used = self._used.get(field)
+        if used is None:  # built whole at construction (l7g)
+            return
+        lo, hi = self._built[field], min(hi, len(used))
+        if hi <= lo:
+            return
+        L = self.widths[field]
+        data, lens, valid = self._tables[field]
+        data[lo:hi], lens[lo:hi], valid[lo:hi] = _gather_table_field(
+            self._blob, self._offsets, used[lo:hi], L, fixed_len=L)
+        # ctlint: disable=unbounded-registry  # keyed by the five string fields, all set at construction
+        self._built[field] = hi
 
     def _field(self, name: str, idx: np.ndarray):
         data, lens, valid = self.tables[name]
@@ -1206,61 +1254,78 @@ _TABLE_FIELDS = (("path", "path"), ("method", "method"),
 
 def _stage_tables_step(arrays: Dict[str, jax.Array],
                        tables: Dict[str, tuple],
+                       earlier: Dict[str, tuple],
                        impl: str = "gather"
-                       ) -> Dict[str, jax.Array]:
-    """All five per-field table scans as ONE traced program. Fusing
-    them matters twice over: one dispatch instead of ~40 eager ops per
-    staging (the eager per-field loop cost ~0.3s of pure dispatch on
-    CPU), and one XLA executable big enough to clear the persistent
-    compilation cache's min-compile-time bar — a fresh process restages
-    a repeat capture shape from disk in milliseconds instead of
-    recompiling five sub-threshold programs (~2s, the dominant
-    stage_ms phase of the tier-1 CPU config).
+                       ) -> Dict[str, Dict[str, jax.Array]]:
+    """One row piece of the per-field table scans as ONE traced
+    program (:func:`stage_capture_tables`): each field in ``tables``
+    is scanned, and a field whose ``earlier`` pieces' outputs are
+    passed is joined to them here, so the whole table's words leave
+    the program that scans its last piece. Fusing the fields matters
+    twice over: one dispatch instead of ~40 eager ops per staging (the
+    eager per-field loop cost ~0.3s of pure dispatch on CPU), and one
+    XLA executable big enough to clear the persistent compilation
+    cache's min-compile-time bar — a fresh process restages a repeat
+    capture shape from disk in milliseconds instead of recompiling
+    five sub-threshold programs (~2s, the dominant stage_ms phase of
+    the tier-1 CPU config).
 
-    With a factored resolve plan staged (``rp_path_gaccept``,
-    engine/megakernel.py) the path table also emits per-row GROUP
-    words — a second accept read off the same final states, bank-ORed
-    into the ``"path_groups"`` table the fused capture resolve
-    gathers. ``impl`` is trace-static (the engine resolves it at
+    Returns ``{field: {key: words}}``. With a factored resolve plan
+    staged (``rp_path_gaccept``, engine/megakernel.py) the path table
+    also emits per-row GROUP words — a second accept read off the same
+    final states, bank-ORed into the ``"path_groups"`` table the fused
+    capture resolve gathers. The frontend l7g table (present only when
+    the policy carries its automaton) scans exactly like the five
+    string fields. ``impl`` is trace-static (the engine resolves it at
     staging; see dfa_kernel.resolve_impl)."""
-    tw: Dict[str, jax.Array] = {}
-    table_fields = _TABLE_FIELDS
-    if "l7g_trans" in arrays and "l7g" in tables:
-        # frontend serialized-record table: scanned through the l7g
-        # automaton exactly like the five string fields (static under
-        # jit — policies without frontend rules skip it wholesale)
-        table_fields = table_fields + (("l7g", "l7g"),)
-    for field, prefix in table_fields:
+    out: Dict[str, Dict[str, jax.Array]] = {}
+    for field, prefix in _TABLE_FIELDS + (("l7g", "l7g"),):
+        if field not in tables:
+            continue
         data, lens, valid = tables[field]
         want_groups = field == "path" and "rp_path_gaccept" in arrays
-        out = dfa_scan_banked(
+        res = dfa_scan_banked(
             arrays[f"{prefix}_trans"], arrays[f"{prefix}_byteclass"],
             arrays[f"{prefix}_start"], arrays[f"{prefix}_accept"],
             data, lens, impl=impl,
             extra_accept=(arrays["rp_path_gaccept"] if want_groups
                           else None))
+        tw: Dict[str, jax.Array] = {}
         if want_groups:
-            words, gw3 = out
+            words, gw3 = res
             gwords = jax.lax.reduce(gw3, jnp.uint32(0),
                                     jax.lax.bitwise_or, (1,))
             tw["path_groups"] = jnp.where(valid[:, None], gwords, 0)
         else:
-            words = out
+            words = res
         flat = words.reshape(data.shape[0], -1)
         tw[field] = jnp.where(valid[:, None], flat, 0)
-    return tw
+        prior = earlier.get(field, ())
+        out[field] = {key: jnp.concatenate([p[key] for p in prior] + [w])
+                      if prior else w for key, w in tw.items()}
+    return out
 
 
 @functools.lru_cache(maxsize=8)
 def _stage_tables_jit(impl: str):
     """One jitted staging program per static impl — the env pick
-    resolves on the host, never under trace."""
+    resolves on the host, never under trace. Its module is named
+    ``jit__unknown`` (a partial has no name), which the benchmark's
+    ``megakernel_roofline`` counts as the scan's device time."""
     return jax.jit(functools.partial(_stage_tables_step, impl=impl))
 
 from cilium_tpu.engine.memo import memo_pack as _memo_pack  # noqa: E402
 
 #: jitted verdict-output → [N, 9] int32 packer (memo fill path)
 _MEMO_PACK_STEP = jax.jit(_memo_pack)
+
+
+#: rows of a capture string table built and scanned as one piece by
+#: :func:`stage_capture_tables`. A power of two, so every pow2-padded
+#: table splits evenly. Small enough that the first piece's build,
+#: which no scan hides, is short; large enough that a piece's dispatch
+#: costs little beside its scan.
+TABLE_PIECE_ROWS = 8192
 
 
 def stage_capture_tables(engine: "VerdictEngine",
@@ -1271,18 +1336,42 @@ def stage_capture_tables(engine: "VerdictEngine",
     LRU (``pkg/fqdn/re``); here the whole capture string table is the
     cache, computed in one batched scan — per-chunk replay then only
     GATHERS match words by row index (:func:`verdict_step_capture`),
-    so the DFA cost scales with UNIQUE strings, not flows. All five
-    fields scan in one fused jitted program (:func:`_stage_tables_step`)
-    so staging costs one dispatch and one persistently-cacheable
-    compile."""
-    host_tables = {field: feat.tables[field]
-                   for field, _ in _TABLE_FIELDS}
-    if "l7g" in feat.tables and "l7g_trans" in engine._arrays:
-        host_tables["l7g"] = feat.tables["l7g"]
-    # one batched pytree transfer, not one device_put per field
-    tables = jax.device_put(host_tables, engine.device)
+    so the DFA cost scales with UNIQUE strings, not flows.
+
+    The tables go to the device in pieces of :data:`TABLE_PIECE_ROWS`
+    rows: piece k holds rows ``[k*P, (k+1)*P)`` of every table that
+    long, is built on the host (:meth:`CaptureFeaturizer.table_rows`),
+    uploaded, and its fields scanned in one fused jitted program
+    (:func:`_stage_tables_step`), asynchronously — the device scans
+    piece k while the host builds piece k+1, and the host's later
+    staging runs behind the last scans. A table's pieces are joined in
+    the program that scans its last one, so the returned words are
+    the whole tables', bit-identical to one scan of each. Tables of at
+    most one piece stage in a single dispatch."""
+    rows = feat.padded_rows()
+    fields = [field for field, _ in _TABLE_FIELDS]
+    if "l7g" in rows and "l7g_trans" in engine._arrays:
+        fields.append("l7g")
     step = _stage_tables_jit(getattr(engine, "_dfa_impl", "gather"))
-    return step(engine._arrays, tables)
+    P = TABLE_PIECE_ROWS
+    table_words: Dict[str, jax.Array] = {}
+    held: Dict[str, list] = {}  # field → its earlier pieces' words
+    for lo in range(0, max(rows[f] for f in fields), P):
+        with _span("capture.stage.table_piece"):
+            piece = {f: feat.table_rows(f, lo, lo + P)
+                     for f in fields if lo < rows[f]}
+            last = {f for f in piece if lo + P >= rows[f]}
+            earlier = {f: tuple(held.pop(f)) for f in last if f in held}
+            # ctlint: disable=hot-loop-h2d  # piece k's rows exist only once the host has built them, and its upload and scan overlap the build of piece k+1: that overlap is the pipeline
+            dev = jax.device_put(piece, engine.device)
+            out = step(engine._arrays, dev, earlier)
+        for f in piece:
+            _METRICS.inc(_CAPTURE_TABLE_PIECES, labels={"field": f})
+            if f in last:
+                table_words.update(out[f])
+            else:
+                held.setdefault(f, []).append(out[f])
+    return table_words
 
 
 def verdict_step_capture(arrays: Dict[str, jax.Array],
@@ -1933,6 +2022,7 @@ import time as _time
 from cilium_tpu.runtime import faults as _faults
 from cilium_tpu.runtime.metrics import (
     CAPTURE_STAGE_SECONDS as _CAPTURE_STAGE_SECONDS,
+    CAPTURE_TABLE_PIECES as _CAPTURE_TABLE_PIECES,
     METRICS as _METRICS,
 )
 from cilium_tpu.runtime.tracing import (

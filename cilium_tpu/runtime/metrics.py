@@ -89,6 +89,9 @@ ENGINE_HOST_SYNCS = "cilium_tpu_engine_host_syncs_total"
 #: capture-replay session staging, split by phase (tables / featurize
 #: / dedup / table-h2d) — the 12.5s ``stage_ms`` decomposed
 CAPTURE_STAGE_SECONDS = "cilium_tpu_capture_stage_seconds"
+#: string-table row pieces dispatched to the device scan, by field
+#: (engine/verdict.stage_capture_tables: one per piece of each table)
+CAPTURE_TABLE_PIECES = "cilium_tpu_capture_table_pieces_total"
 #: collective ops recorded by the trace-time ledger, by site/op/axis
 #: (counts are per compiled block execution — see parallel/collectives)
 COLLECTIVE_OPS = "cilium_tpu_collective_ops_total"
@@ -732,6 +735,9 @@ METRICS.describe(CAPTURE_STAGE_SECONDS,
                  "capture-replay session staging seconds, by phase",
                  buckets=(0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0,
                           2.5, 5.0, 10.0, 30.0, 60.0, 120.0))
+METRICS.describe(CAPTURE_TABLE_PIECES,
+                 "capture string-table row pieces dispatched to the "
+                 "device scan, by field")
 METRICS.describe(COLLECTIVE_OPS,
                  "collective ops recorded at trace time, by "
                  "site/op/axis (count per compiled block)")

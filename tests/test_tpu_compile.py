@@ -206,3 +206,30 @@ def test_dp_step_compiles_on_4_device_mesh(http_1k, topo,
     compiled = step.lower(arrays, batch).compile()
     assert len(compiled.output_shardings["verdict"].device_set) == 4
     _fits(compiled)
+
+
+def test_table_piece_scans_compile_at_1k_rule_shapes(http_1k, one_chip):
+    """The capture string-table scan of one row piece, and of the last
+    piece joined to the earlier ones (``stage_capture_tables``), at the
+    1k-rule policy's path banks: a 65,536-row path table, 32 bytes
+    wide."""
+    import jax
+
+    from cilium_tpu.engine import verdict as V
+
+    rows = V.TABLE_PIECE_ROWS
+    pieces = 65536 // rows
+    step = V._stage_tables_jit("gather")
+    arrays = _sds(http_1k["engine"]._arrays, one_chip)
+    piece = _sds({"path": (np.zeros((rows, 32), np.uint8),
+                           np.zeros(rows, np.int32),
+                           np.zeros(rows, bool))}, one_chip)
+    words = jax.eval_shape(step, arrays, piece, {})["path"]
+    earlier = _sds({"path": tuple(
+        {k: np.zeros(s.shape, s.dtype) for k, s in words.items()}
+        for _ in range(pieces - 1))}, one_chip)
+    for prior, n in (({}, 1), (earlier, pieces)):
+        compiled = step.lower(arrays, piece, prior).compile()
+        _fits(compiled)
+        out = jax.eval_shape(step, arrays, piece, prior)["path"]
+        assert {s.shape[0] for s in out.values()} == {n * rows}
