@@ -3,7 +3,9 @@
 ``run(env) -> dict`` with the keys ``e2e`` (end-to-end metric values),
 ``attempted``, ``failed``, ``checks`` (``compare.checks``),
 ``memory_peak_bytes`` and ``ctx`` (what the per-layer readers of
-``benchmark/metrics/`` read).
+``benchmark/metrics/`` read). Each also declares ``TINY``, the traffic
+keys the CPU rehearsal (``tests/benchmark/``) cuts to a size the CPU
+runs in a second or two.
 """
 
 from __future__ import annotations
@@ -14,6 +16,7 @@ import shutil
 import time
 from typing import Optional
 
+from benchmark import spans as _spans
 from benchmark import trace as _trace
 
 
@@ -53,7 +56,7 @@ def traced(env):
     finally:
         jax.profiler.stop_trace()
     t0 = time.perf_counter()
-    reduced = _trace.reduce_dir(env.trace_dir)
+    reduced = _spans.reduce_dir(env.trace_dir)
     if reduced is not None:
         result.update(reduced, reduce_s=time.perf_counter() - t0)
     shutil.rmtree(env.trace_dir, ignore_errors=True)

@@ -29,6 +29,10 @@ from benchmark.kinds import memory_peak, span, traced
 from benchmark.program import Program
 from benchmark.worlds import world_module
 
+#: the traffic keys the CPU rehearsal (``tests/benchmark/``) cuts
+TINY = {"segment_records": 512, "compare_per_segment": 32,
+        "compare_max": 4096}
+
 
 def _pick(lane, idx) -> np.ndarray:
     """The sampled records' answers; -1 where the lane has none (an

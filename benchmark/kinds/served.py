@@ -37,6 +37,9 @@ from benchmark.worlds import world_module
 DRAIN_S = 60.0
 #: how long the load generator may take to connect and warm up
 SETUP_WAIT_S = 300.0
+#: the traffic keys the CPU rehearsal (``tests/benchmark/``) cuts
+TINY = {"chunk_records": 64, "pool_images": 8, "connections": 2,
+        "rate_records_s": 4000}
 
 
 def _recv(pipe, proc, timeout: float, what: str):

@@ -1,5 +1,6 @@
 """Ring packs (``cilium_tpu_serve_pack_records`` observations; each pack
-is one fused dispatch and one readback) ÷ chunks sent in the window."""
+is one fused dispatch, and the verdicts are read back once per chunk,
+``ServeLoop._resolve_ticket``) ÷ chunks sent in the window."""
 
 
 def read(ctx):

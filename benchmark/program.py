@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import os
 import types
-from typing import Dict, List
+from typing import Dict, List, Sequence
 
 from benchmark.worlds import resolve, to_flow
 
@@ -177,9 +177,12 @@ class Program:
 
     # -- counters --------------------------------------------------------
     @staticmethod
-    def counters() -> Dict[str, float]:
+    def counters(hosts: Sequence[str] = ()) -> Dict[str, float]:
         """The program's counters the per-layer metrics read, as one
-        snapshot (the harness takes one at each edge of the window)."""
+        snapshot (the harness takes one at each edge of the window).
+        Each is summed over its unlabelled series and, for each host
+        named, its ``{"host": h}`` series: fleet replicas record the
+        serve counters under their host's label."""
         from cilium_tpu.runtime.metrics import (
             CAPTURE_STAGE_SECONDS,
             METRICS,
@@ -187,11 +190,17 @@ class Program:
             SERVE_PACK_STREAMS,
         )
 
-        out = {f"stage_s.{p}": METRICS.histo_sum(CAPTURE_STAGE_SECONDS,
-                                                 {"phase": p})
+        scopes = [{}] + [{"host": h} for h in hosts]
+
+        def total(read, name, labels=None):
+            return float(sum(read(name, {**(labels or {}), **s})
+                             for s in scopes))
+
+        out = {f"stage_s.{p}": total(METRICS.histo_sum,
+                                     CAPTURE_STAGE_SECONDS, {"phase": p})
                for p in STAGE_PHASES}
-        out["packs"] = float(METRICS.histo_count(SERVE_PACK_RECORDS))
-        out["pack_streams"] = METRICS.histo_sum(SERVE_PACK_STREAMS)
+        out["packs"] = total(METRICS.histo_count, SERVE_PACK_RECORDS)
+        out["pack_streams"] = total(METRICS.histo_sum, SERVE_PACK_STREAMS)
         return out
 
     def policy_array_bytes(self) -> Dict[str, int]:

@@ -189,13 +189,11 @@ class SpanTrace(trace.Trace):
     """``trace.Trace`` plus the program's spans, keyed by host line,
     and idle gaps named by the tiers above."""
 
-    def __init__(self, path: str):
-        super().__init__(path)
-        from jax.profiler import ProfileData
-
+    def _load(self, pd) -> None:
+        super()._load(pd)
         #: program spans on the host lines, by start
         self.program: List[Span] = []
-        for plane in ProfileData.from_file(path).planes:
+        for plane in pd.planes:
             if not plane.name.startswith("/host:"):
                 continue
             for k, line in enumerate(plane.lines):
@@ -232,6 +230,13 @@ class SpanTrace(trace.Trace):
                 "serve.cycle_gap_p99_ms": cycle_gap_p99_ms(s),
                 "serve.host_syncs_per_chunk": host_syncs_per_chunk(s),
                 "replay.device_wait_pct": device_wait_pct(s, window_s)}
+
+
+def reduce_dir(trace_dir: str, top: int = 10) -> Optional[dict]:
+    """``SpanTrace.reduce`` of the newest trace under ``trace_dir``, or
+    None where there is none: what a traced window reports."""
+    path = trace.find_xplane(trace_dir)
+    return SpanTrace(path).reduce(top) if path else None
 
 
 def main(argv: Optional[List[str]] = None) -> int:

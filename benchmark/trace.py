@@ -59,7 +59,10 @@ class Trace:
     def __init__(self, path: str):
         from jax.profiler import ProfileData
 
-        pd = ProfileData.from_file(path)
+        self._load(ProfileData.from_file(path))
+
+    def _load(self, pd) -> None:
+        """Collect the events of the parsed trace ``pd``."""
         #: device plane → list of (name, start_ns, end_ns) per line kind
         self.ops: Dict[str, List[Tuple[str, int, int]]] = {}
         self.modules: Dict[str, List[Tuple[str, int, int]]] = {}
@@ -146,8 +149,3 @@ class Trace:
             if cover > 0 and key > best_key:
                 best, best_key = name, key
         return best
-
-
-def reduce_dir(trace_dir: str, top: int = 10) -> Optional[dict]:
-    path = find_xplane(trace_dir)
-    return Trace(path).reduce(top) if path else None

@@ -1,9 +1,11 @@
 """Fixtures of the benchmark's CPU rehearsal: a tiny copy of the
 benchmark's data (fewer rules, small segments and chunks) and a run of
-a cell on the CPU with the chip gate skipped."""
+a cell on the CPU, on as many devices as the cell's ``chips``, with the
+chip gate skipped."""
 
 from __future__ import annotations
 
+import importlib
 import json
 import os
 import shutil
@@ -13,14 +15,16 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-#: what the tiny copy changes, per traffic kind / config key
-TINY_TRAFFIC = {
-    "replay": {"segment_records": 512, "compare_per_segment": 32,
-               "compare_max": 4096},
-    "served": {"chunk_records": 64, "pool_images": 8, "connections": 2,
-               "rate_records_s": 4000},
-}
+#: what the tiny copy changes in each config
 TINY_RULES = 50
+
+
+def tiny_traffic(kind: str) -> dict:
+    """The traffic keys the tiny copy cuts: ``TINY`` of the kind's
+    module, or nothing where it declares none (its own test,
+    ``test_every_kind_declares_its_tiny_sizes``, fails then)."""
+    mod = importlib.import_module(f"benchmark.kinds.{kind}")
+    return getattr(mod, "TINY", {})
 
 
 def make_tiny_root(dst: str) -> str:
@@ -46,7 +50,7 @@ def make_tiny_root(dst: str) -> str:
     for f in os.listdir(src):
         with open(os.path.join(src, f)) as fh:
             t = json.load(fh)
-        t.update(TINY_TRAFFIC[t["kind"]])
+        t.update(tiny_traffic(t["kind"]))
         with open(os.path.join(dst, "benchmark", "traffic", f), "w") as fh:
             json.dump(t, fh)
     return dst
@@ -71,7 +75,10 @@ def run_tiny(tiny_root, tmp_path, monkeypatch):
     def go(cell, trace=False, control=False, seed=2**31 + 17,
            seconds=1.0):
         lines = []
-        out = run.run_cell(cell, seed, seconds, trace, jax.devices()[:1],
+        chips = run.cell_parts(run.benchmark_spec(tiny_root), cell,
+                               tiny_root)[0]["chips"]
+        assert len(jax.devices()) >= chips
+        out = run.run_cell(cell, seed, seconds, trace, jax.devices()[:chips],
                            root=tiny_root, control=control,
                            log=lines.append,
                            cache_dir=str(tmp_path / "cache"))

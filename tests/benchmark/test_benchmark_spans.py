@@ -7,6 +7,7 @@ five per-layer readers, and the tiny cells run traced on the CPU."""
 from __future__ import annotations
 
 import os
+import shutil
 from collections import Counter
 
 import pytest
@@ -66,6 +67,25 @@ def test_span_trace_keeps_the_reduction(path):
     got = spans.SpanTrace(path).reduce()
     for key in ("busy_s", "window_s", "module_s", "device_ops"):
         assert got[key] == want[key], key
+
+
+@pytest.mark.parametrize("path", [SMALL, SPANS])
+def test_traced_window_reduction_names_gaps_by_program_spans(tmp_path, path):
+    """What a traced window reduces its trace directory with
+    (``spans.reduce_dir``): the harness reduction's numbers, identical,
+    with the same idle gaps named by the program's spans."""
+    shutil.copy(path, tmp_path / "t.xplane.pb")
+    want = trace.Trace(path).reduce()
+    got = spans.reduce_dir(str(tmp_path))
+    for key in ("busy_s", "window_s", "module_s", "device_ops"):
+        assert got[key] == want[key], key
+    assert [g[1] for g in got["idle_gaps"]] == [g[1] for g in
+                                               want["idle_gaps"]]
+    names = [g[0] for g in got["idle_gaps"]]
+    if path == SPANS:
+        assert any(n.startswith(P) for n in names), names
+    else:
+        assert names[:3] == [trace.PREFIX + "host"] * 3
 
 
 def test_idle_gaps_named_by_tier(st):

@@ -9,7 +9,7 @@ import os
 
 import pytest
 
-from benchmark import peaks, trace
+from benchmark import peaks, spans, trace
 
 SMALL = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                      "small_tpu.xplane.pb")
@@ -53,7 +53,7 @@ def test_benchmark_trace_union_and_clip():
 
 
 def test_benchmark_trace_without_window_reads_nothing(tmp_path):
-    assert trace.reduce_dir(str(tmp_path)) is None
+    assert spans.reduce_dir(str(tmp_path)) is None
 
 
 def test_benchmark_peaks_known_and_unknown():
