@@ -22,7 +22,10 @@ automaton pass replaces"). Design notes:
 Implementation choice is a TRACE-STATIC argument: callers resolve it
 once on the host (``resolve_impl()`` reads the env; the engine does it
 at staging) and thread it through — nothing here reads the
-environment or probes the backend under trace.
+environment under trace. The default pick, ``"auto"``, is settled per
+bank stack by :func:`pick_arm` from static shapes and the process's
+default backend: the Pallas MXU kernel (``engine/pallas_dfa.py``) for
+wide banks over full tiles on the TPU, the gather everywhere else.
 """
 
 from __future__ import annotations
@@ -34,27 +37,37 @@ import jax.numpy as jnp
 from jax import lax
 
 
+#: the pick when ``CILIUM_TPU_DFA_IMPL`` is unset: each bank stack takes
+#: the arm :func:`pick_arm` gives for its shape and batch
+IMPL_AUTO = "auto"
+
+
 def resolve_impl(env=None) -> str:
     """HOST-side step-implementation resolution — call once at
     engine/bank staging and thread the result as a static argument
     (never under trace: flipping the env between traces would
     otherwise be an invisible recompile lever).
 
-    The arms (none has been timed on a locally attached chip yet —
-    PERF.md):
+    Unset, the pick is :data:`IMPL_AUTO`: every bank stack takes the
+    arm :func:`pick_arm` gives for its shape. The explicit picks:
 
     * "gather" — one transition-table lookup per (flow, byte, bank).
-      Algorithmically minimal work — the default everywhere.
-    * "pallas" — engine/pallas_dfa.py MXU matmul step: data-oblivious
-      (RE2-style input-independent timing) but pays K×S MACs per
-      lookup; needs ≤128 states/bank. Kept as an option for
-      constant-time-guarantee deployments.
+      Algorithmically minimal work, but a per-index cost on the chip:
+      8.8 ns a lookup on a v5e at the replay cell's path piece (7
+      banks × 2,048 states × 28 classes, 8,192 rows × 32 bytes), and
+      the same at 128 or 8,192 states (PERF.md §6).
+    * "pallas" — engine/pallas_dfa.py MXU step: data-oblivious
+      (RE2-style input-independent timing) for every bank, ≤
+      ``pallas_dfa.MAX_STATES`` padded states each. 2.0 ns a lookup
+      at the same piece (TILE 1024), 0.6 ns at 128 states, 6.1 ns at
+      8,192.
     * "onehot" — the matmul formulation in plain XLA (any state
       count); portable reference implementation.
 
     Pallas kernels compile for the TPU only: on any other backend the
-    "pallas" pick resolves to "gather" (tests that want the Pallas
-    interpreter call the kernel with ``interpret=True`` themselves).
+    "pallas" pick resolves to "gather" and the auto rule picks the
+    gather (tests that want the Pallas interpreter call the kernel
+    with ``interpret=True`` themselves).
     """
     import os
 
@@ -64,7 +77,43 @@ def resolve_impl(env=None) -> str:
         return "gather"
     if pick in ("gather", "onehot", "pallas"):
         return pick
+    return IMPL_AUTO
+
+
+def pick_arm(backend: str, states: int, classes: int, rows: int) -> str:
+    """The arm a bank stack takes under :data:`IMPL_AUTO`: "pallas" on
+    the TPU for banks of more than 128 padded states (up to the
+    kernel's ``MAX_STATES``, at most ``MAX_CLASSES`` byte classes)
+    over a batch that fills at least one kernel tile; "gather"
+    everywhere else. Both arms give the same final states. The MXU
+    step's cost grows with the padded state count, the gather's per
+    lookup is flat; the smaller banks and sub-tile batches (the
+    method/host/hdr/dns stacks, the served path's 256-row chunks)
+    keep the gather they had."""
+    if backend != "tpu":
+        return "gather"
+    from cilium_tpu.engine import pallas_dfa
+
+    sp = pallas_dfa.padded_states(states)
+    # ctlint: disable=recompile-hazard  # the arm is a trace-time static of the bank shape, by design: one compile per shape either way
+    if (128 < sp <= pallas_dfa.MAX_STATES
+            and classes <= pallas_dfa.MAX_CLASSES
+            and rows >= pallas_dfa.TILE):
+        return "pallas"
     return "gather"
+
+
+def scan_arm(impl: Optional[str], trans_shape, rows: int) -> str:
+    """The arm :func:`dfa_finals_banked` runs for a ``[NB, S, K]`` bank
+    stack over ``rows`` rows under ``impl`` (None is
+    :data:`IMPL_AUTO`): the explicit picks as given, the auto pick by
+    :func:`pick_arm` on the default backend. Shapes are static, so
+    this is a host-side decision even under trace."""
+    impl = impl or IMPL_AUTO
+    if impl != IMPL_AUTO:
+        return impl
+    _nb, states, classes = trans_shape
+    return pick_arm(jax.default_backend(), states, classes, rows)
 
 
 def dfa_scan(
@@ -79,10 +128,11 @@ def dfa_scan(
 
     ``impl``: "gather" (one gather per step; the default) or "onehot"
     (two f32 matmuls per step — exact for state ids < 2^24,
-    MXU-friendly). A trace-static choice; None means "gather".
+    MXU-friendly). A trace-static choice; None, "auto" and "pallas"
+    mean "gather" here (the banked entry runs the Pallas kernel).
     """
     impl = impl or "gather"
-    if impl == "pallas":
+    if impl in ("pallas", IMPL_AUTO):
         impl = "gather"  # single-bank path: pallas handled in banked entry
     if impl not in ("gather", "onehot"):
         raise ValueError(f"unknown dfa impl {impl!r}")
@@ -149,14 +199,16 @@ def dfa_finals_banked(
     interpret: bool = False,
 ) -> jax.Array:
     """Final DFA states for every (bank, flow) → [NB, B] int32; the
-    accept-table reads layer on top (``dfa_scan_banked``)."""
-    impl = impl or "gather"
-    if impl == "pallas":
+    accept-table reads layer on top (``dfa_scan_banked``). ``impl``
+    is resolved per bank stack by :func:`scan_arm`."""
+    arm = scan_arm(impl, trans.shape, data.shape[0])
+    if arm == "pallas":
         from cilium_tpu.engine import pallas_dfa
 
         # pallas is an explicit opt-in for its input-independent
         # timing guarantee: a bank it cannot hold is an error, never a
-        # silent swap to the data-dependent gather
+        # silent swap to the data-dependent gather (the auto rule
+        # only picks banks the kernel holds)
         # ctlint: disable=recompile-hazard  # bank-shape check is a trace-time static, by design
         if not pallas_dfa.pallas_supported(trans.shape):
             raise ValueError(
@@ -168,7 +220,7 @@ def dfa_finals_banked(
             trans, byteclass, start, data, lengths,
             interpret=interpret)
     return jax.vmap(
-        lambda tr, bc, st: dfa_scan(tr, bc, st, data, lengths, impl=impl)
+        lambda tr, bc, st: dfa_scan(tr, bc, st, data, lengths, impl=arm)
     )(trans, byteclass, start)              # [NB, B]
 
 
@@ -186,16 +238,16 @@ def dfa_scan_banked(
     """All banks over one batch → accept words ``[B, NB, W]`` uint32.
 
     ``impl``/``interpret`` are trace-static (resolve on the host via
-    :func:`resolve_impl`; None = "gather"; ``interpret`` runs a Pallas
+    :func:`resolve_impl`; None = "auto"; ``interpret`` runs a Pallas
     pick in the interpreter, for CPU tests). ``extra_accept``
     ([NB, S, Wg]) reads a second accept plane off the same final
     states — the megakernel's group-accept tables (one extra gather,
     no second scan) — and makes the return a ``(words, extra_words)``
     tuple."""
-    impl = impl or "gather"
     finals = dfa_finals_banked(trans, byteclass, start, data, lengths,
                                impl=impl, interpret=interpret)
-    word_impl = "gather" if impl == "pallas" else impl
+    # the accept read stays a gather under every pick but "onehot"
+    word_impl = "onehot" if impl == "onehot" else "gather"
 
     def extract(acc):
         words = jax.vmap(

@@ -35,8 +35,8 @@ the bitset-NFA "rules-as-lanes" arm (``engine/nfa_kernel.py`` /
 Which wins is a property of the bank *shape* (DFA state count vs NFA
 position count, class count, backend), so the pick is made per field
 stack at engine staging — heuristically under ``kernel_impl=auto``
-(dense everywhere except TPU banks whose DFA busts the 128-state
-Pallas budget while their positions fit), measured under
+(dense everywhere except TPU banks whose DFA passes 128 states while
+their NFA positions fit one 128-lane tile), measured under
 ``kernel_impl=autotune`` — cached process-wide by shape+backend key,
 recorded on the policy's kernel plan and the loader's bank registry,
 and carried across warm restarts through the snapshot. Every arm is
@@ -817,12 +817,12 @@ def plan_for_engine(policy, cfg) -> Tuple[
 
     for prefix, matcher in matchers.items():
         trans = policy.arrays[f"{prefix}_trans"]
-        dense_pallas_ok = trans.shape[1] <= 128
+        small_dfa = trans.shape[1] <= 128
         # only pay the NFA construction when the mode can actually use
         # it: forced/measured picks always, the heuristic only in its
-        # one preferred regime (TPU + dense-Pallas-ineligible banks)
+        # one preferred regime (TPU + banks past 128 DFA states)
         want_nfa = (mode in ("autotune", IMPL_NFA)
-                    or (mode == "auto" and not dense_pallas_ok
+                    or (mode == "auto" and not small_dfa
                         and jax.default_backend() == "tpu"))
         nfa_banks = None
         if not degraded and want_nfa:
@@ -847,10 +847,11 @@ def plan_for_engine(policy, cfg) -> Tuple[
                                   nfa_stacked, widths[prefix],
                                   interpret=False)
         elif mode == "auto" and jax.default_backend() == "tpu" \
-                and not dense_pallas_ok and nfa_stacked is not None:
+                and not small_dfa and nfa_stacked is not None:
             # the one regime where the heuristic prefers the NFA arm
-            # without measuring: the dense Pallas kernel can't hold the
-            # bank (DFA blew the 128-state tile) but the positions fit
+            # without measuring: the DFA passed 128 states but the
+            # positions fit one tile (not re-timed against the dense
+            # MXU kernel since it took banks past 128 states)
             pick = {"impl": IMPL_NFA, "dense_ms": None, "nfa_ms": None}
         else:
             pick = {"impl": IMPL_DENSE, "dense_ms": None,

@@ -37,7 +37,7 @@ from cilium_tpu.policy.api.l7 import L7Rules, PortRuleDNS, PortRuleHTTP, PortRul
 from cilium_tpu.policy.compiler import matchpattern
 from cilium_tpu.policy.compiler.dfa import BankedDFA, DFABank, compile_patterns
 from cilium_tpu.policy.mapstate import MapState
-from cilium_tpu.engine.dfa_kernel import dfa_scan_banked
+from cilium_tpu.engine.dfa_kernel import dfa_scan_banked, scan_arm
 from cilium_tpu.engine.search import lower_bound
 from cilium_tpu.engine.mapstate_kernel import PackedMapState, pack_mapstate, mapstate_lookup
 
@@ -1352,7 +1352,9 @@ def stage_capture_tables(engine: "VerdictEngine",
     fields = [field for field, _ in _TABLE_FIELDS]
     if "l7g" in rows and "l7g_trans" in engine._arrays:
         fields.append("l7g")
-    step = _stage_tables_jit(getattr(engine, "_dfa_impl", "gather"))
+    impl = getattr(engine, "_dfa_impl", "gather")
+    step = _stage_tables_jit(impl)
+    prefix_of = dict(_TABLE_FIELDS + (("l7g", "l7g"),))
     P = TABLE_PIECE_ROWS
     table_words: Dict[str, jax.Array] = {}
     held: Dict[str, list] = {}  # field → its earlier pieces' words
@@ -1366,7 +1368,10 @@ def stage_capture_tables(engine: "VerdictEngine",
             dev = jax.device_put(piece, engine.device)
             out = step(engine._arrays, dev, earlier)
         for f in piece:
-            _METRICS.inc(_CAPTURE_TABLE_PIECES, labels={"field": f})
+            arm = scan_arm(impl, engine._arrays[f"{prefix_of[f]}_trans"].shape,
+                           len(piece[f][0]))
+            _METRICS.inc(_CAPTURE_TABLE_PIECES,
+                         labels={"field": f, "arm": arm})
             if f in last:
                 table_words.update(out[f])
             else:

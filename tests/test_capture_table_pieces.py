@@ -65,7 +65,9 @@ def _assert_words_equal(got, want):
 
 
 def _pieces():
-    return {f: METRICS.get(CAPTURE_TABLE_PIECES, {"field": f})
+    # every field takes the gather off the TPU (dfa_kernel.pick_arm)
+    return {f: METRICS.get(CAPTURE_TABLE_PIECES,
+                           {"field": f, "arm": "gather"})
             for f in FIELDS}
 
 

@@ -9,7 +9,8 @@ it, or the 4-device mesh — and hold each program's
 
 * ``nfa_finals_pallas`` at path width 256 and header width 1024,
   batch 8192, at the 1k-rule http policy's bank and class counts;
-* ``dfa_finals_pallas`` at the 128-state budget;
+* ``dfa_finals_pallas`` at its state ceiling, and at the 1k-rule path
+  stack's own bank, state and class counts (widths 32 and 256);
 * ``fused_verdict_step`` at the 1k-rule http policy's array shapes
   with the impl plan the TPU ``auto`` plan picks;
 * the DP lane step over a 4-device mesh.
@@ -113,9 +114,10 @@ def _fits(compiled) -> int:
 
 def test_tpu_auto_plan_at_1k_rules(http_1k):
     """The plan the fused-step compile below uses. The 1k-rule path
-    banks blow the dense Pallas kernel's 128-state tile AND the NFA
-    arm's 128-position tile, so ``auto`` on TPU keeps every field on
-    the dense gather arm at this policy."""
+    banks blow the NFA arm's 128-position tile, so ``auto`` on TPU
+    keeps every field on the dense arm at this policy (within it,
+    ``dfa_kernel.pick_arm`` sends the path banks to the MXU kernel
+    over batches of a full tile)."""
     from cilium_tpu.engine.megakernel import IMPL_DENSE
 
     rep = http_1k["report"]["path"]
@@ -155,6 +157,29 @@ def test_dfa_pallas_compiles_at_state_budget(one_chip):
 
     nb, k, width = 2, 32, 1024
     args = _sds([np.zeros((nb, MAX_STATES, k), np.int32),
+                 np.zeros((nb, 256), np.int32),
+                 np.zeros((nb,), np.int32),
+                 np.zeros((BATCH, width), np.uint8),
+                 np.zeros((BATCH,), np.int32)], one_chip)
+    compiled = jax.jit(functools.partial(
+        dfa_finals_pallas, interpret=False)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+
+
+@pytest.mark.parametrize("width", [32, 256])
+def test_dfa_pallas_compiles_at_1k_rule_path_stack(http_1k, one_chip,
+                                                   width):
+    """The MXU kernel at the shape the default pick sends it on the
+    chip: the 1k-rule path banks (>128 states each), batch 8192."""
+    import jax
+
+    from cilium_tpu.engine.dfa_kernel import pick_arm
+    from cilium_tpu.engine.pallas_dfa import dfa_finals_pallas
+
+    nb, s, k = http_1k["arrays"]["path_trans"].shape
+    assert pick_arm("tpu", s, k, BATCH) == "pallas"
+    args = _sds([np.zeros((nb, s, k), np.int32),
                  np.zeros((nb, 256), np.int32),
                  np.zeros((nb,), np.int32),
                  np.zeros((BATCH, width), np.uint8),
@@ -208,18 +233,21 @@ def test_dp_step_compiles_on_4_device_mesh(http_1k, topo,
     _fits(compiled)
 
 
-def test_table_piece_scans_compile_at_1k_rule_shapes(http_1k, one_chip):
+@pytest.mark.parametrize("arm", ["gather", "pallas"])
+def test_table_piece_scans_compile_at_1k_rule_shapes(http_1k, one_chip,
+                                                     arm):
     """The capture string-table scan of one row piece, and of the last
     piece joined to the earlier ones (``stage_capture_tables``), at the
     1k-rule policy's path banks: a 65,536-row path table, 32 bytes
-    wide."""
+    wide. The arm is named: the default pick reads the backend, which
+    is the CPU here; on the chip it takes "pallas" for these banks."""
     import jax
 
     from cilium_tpu.engine import verdict as V
 
     rows = V.TABLE_PIECE_ROWS
     pieces = 65536 // rows
-    step = V._stage_tables_jit("gather")
+    step = V._stage_tables_jit(arm)
     arrays = _sds(http_1k["engine"]._arrays, one_chip)
     piece = _sds({"path": (np.zeros((rows, 32), np.uint8),
                            np.zeros(rows, np.int32),
@@ -231,5 +259,7 @@ def test_table_piece_scans_compile_at_1k_rule_shapes(http_1k, one_chip):
     for prior, n in (({}, 1), (earlier, pieces)):
         compiled = step.lower(arrays, piece, prior).compile()
         _fits(compiled)
+        assert ("tpu_custom_call" in compiled.as_text()) == (
+            arm == "pallas")
         out = jax.eval_shape(step, arrays, piece, prior)["path"]
         assert {s.shape[0] for s in out.values()} == {n * rows}
