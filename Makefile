@@ -7,7 +7,7 @@ PY ?= python
         churn-fleet churn-fleet-smoke dst dst-validate serve-soak \
         serve-fleet serve-fleet-smoke canary canary-smoke \
         bench bench-all bench-e2e bench-service bench-regen bench-sp \
-        bench-stage bench-stream bench-kernel bench-multichip \
+        bench-stage bench-stream bench-multichip \
         bench-protocols perf-report check
 
 test:            ## full suite (CPU, virtual 8-device mesh via conftest)
@@ -241,15 +241,6 @@ bench-sp:        ## SP (associative-scan) vs sequential payload scan
 # The cold stage_ms is the number the ISSUE-7 ≥10× budget tracks.
 bench-stage:     ## capture→session staging microbench (phase split)
 	$(PY) bench_stage.py
-
-# bench-kernel: the megakernel microbench — fused verdict step (one
-# dispatch) vs the three-op mapstate/scan/resolve path at the 1k-rule
-# config, plus the per-bank-shape dense-DFA vs bitset-NFA autotune
-# sweep. Provenance-stamped lines land in BENCH_KERNEL_r06.jsonl for
-# perf-report; the lane FAILS (strict gate) if the fused speedup
-# drops below 2x — the ROADMAP megakernel target.
-bench-kernel:    ## fused megakernel vs three-op path + impl sweep
-	$(PY) bench_kernel.py --min-speedup 2.0 --out BENCH_KERNEL_r06.jsonl
 
 bench-stream:    ## online serving path: chunked binary stream transport
 	$(PY) bench_service.py --stream --stream-only --rules 1000 \

@@ -735,8 +735,7 @@ def run_config(config: str, args) -> dict:
         f"(cache dir {cfg.loader.cache_dir})")
 
     fb = encode_flows(scenario.flows, engine.policy.kafka_interns, cfg.engine)
-    # the engine's STAGED step — the fused megakernel unless
-    # CILIUM_TPU_KERNEL_IMPL=legacy, in which case jax.jit(verdict_step)
+    # the engine's STAGED step — the fused megakernel
     # (engine/verdict.py): the device lane measures what serves
     step = engine._step
     arrays = engine._arrays

@@ -11,8 +11,8 @@ One process holds the chip and runs, in order:
    megakernel, every output lane checked against the CPU oracle;
 3. **families** — the ``examples/policies/`` corpus plus one
    ``l7proto`` frontend rule over 20k flows (DNS, Kafka and l7g arms),
-   then the same flows with the bitset-NFA arm forced (the compiled
-   Pallas NFA kernel);
+   then the same flows on the bitset-NFA plan (``megakernel.nfa_plan``:
+   the compiled Pallas NFA kernel);
 4. **served** — ``VerdictService`` with ``serve.enabled``: 8 streams
    × 4 chunks through ``ServeLoop`` + ``VerdictRing``, plus ``check``
    ops through the ``MicroBatcher``;
@@ -195,9 +195,19 @@ def stage_engine(per_identity, workdir: str, device, serve: bool = False,
     off = sorted(k for k, a in engine._arrays.items()
                  if a.devices() != {device})
     check(not off, f"staged arrays not on {device}: {off[:5]}")
+    log_kernel_plan(engine)
+    return loader, engine
+
+
+def log_kernel_plan(engine) -> list:
+    """Print each field's planned scan arm; returns the fields whose
+    NFA arm runs the Pallas kernel (on a TPU backend)."""
+    import jax
+
+    on_tpu = jax.default_backend() == "tpu"
     pallas = []
     for field, rep in sorted(engine.kernel_report.items()):
-        compiled = rep["impl"] == "nfa-bitset" and engine._pallas
+        compiled = rep["impl"] == "nfa-bitset" and on_tpu
         if compiled:
             pallas.append(field)
         log(f"kernel {field}: arm={rep['impl']}"
@@ -205,8 +215,7 @@ def stage_engine(per_identity, workdir: str, device, serve: bool = False,
             f"banks={rep['banks']} dfa_states={rep['dfa_states']} "
             f"nfa_positions={rep['nfa_positions']}")
     log(f"compiled Pallas NFA arm: {pallas or 'no field'}")
-    engine.pallas_fields = pallas
-    return loader, engine
+    return pallas
 
 
 # ------------------------------------------------------------ checking --
@@ -328,21 +337,23 @@ def phase_replay(name: str, per_identity, flows, workdir: str, device,
 
 def phase_nfa_arm(per_identity, flows, replayed, workdir: str, device,
                   meter: CompileMeter, report: dict) -> list:
-    """The fused step with the bitset-NFA arm forced
-    (``kernel_impl="nfa-bitset"``, banks small enough for its
-    128-position tile): on a TPU this compiles and runs the Pallas NFA
-    kernel, which no ``auto`` pick at the other phases' policies
-    reaches. Every lane must equal the replay's (``replayed``: the
+    """The fused step on the bitset-NFA plan (``megakernel.nfa_plan``:
+    every field whose banks fit the arm's 128-position tile, at bank
+    size 4): on a TPU this compiles and runs the Pallas NFA kernel,
+    which the rule's plan at the other phases' policies never reaches.
+    Every lane must equal the replay's (``replayed``: the
     ``phase_replay`` result, itself checked against the oracle).
     Returns the fields that took the arm."""
-    from cilium_tpu.engine.megakernel import IMPL_NFA
+    from cilium_tpu.engine.megakernel import IMPL_NFA, nfa_plan
 
     with timed_phase("nfa_arm.fused", meter, report):
-        _, engine = stage_engine(per_identity, workdir, device,
-                                 kernel_impl=IMPL_NFA, bank_size=4)
+        loader, engine = stage_engine(per_identity, workdir, device,
+                                      bank_size=4)
+        engine.stage_plan(nfa_plan(engine.policy, loader.config.engine))
+        log_kernel_plan(engine)
         out = engine.verdict_flows(flows)
     arm = sorted(f for f, i in engine.impl_plan.items() if i == IMPL_NFA)
-    check(arm, "no field took the forced NFA arm")
+    check(arm, "no field took the NFA arm")
     compare_lanes("nfa arm vs oracle", out, replayed["oracle"],
                   ORACLE_LANES, replayed["oracle_idx"])
     compare_lanes("nfa arm vs replay", out, replayed["lanes"],

@@ -70,13 +70,6 @@ class EngineConfig:
     #: Invalidated on every Loader revision commit — disable to force
     #: every chunk through the full verdict step.
     verdict_memo: bool = True
-    #: verdict-step kernel selection (engine/megakernel.py):
-    #: "auto" = fused megakernel, heuristic per-bank-shape scan pick;
-    #: "autotune" = fused, dense vs bitset-NFA measured per bank shape
-    #: at staging; "dfa-dense"/"nfa-bitset" = fused with the arm
-    #: forced; "legacy" = the pre-megakernel three-family step. Every
-    #: value is verdict-bit-equal — this knob only moves time.
-    kernel_impl: str = "auto"
 
 
 @dataclasses.dataclass
@@ -482,9 +475,6 @@ class Config:
         if env.get("CILIUM_TPU_VERDICT_MEMO", "").lower() in (
                 "0", "false", "no", "off"):
             cfg.engine.verdict_memo = False
-        if env.get("CILIUM_TPU_KERNEL_IMPL", "") in (
-                "auto", "autotune", "dfa-dense", "nfa-bitset", "legacy"):
-            cfg.engine.kernel_impl = env["CILIUM_TPU_KERNEL_IMPL"]
         if "CILIUM_TPU_CACHE_DIR" in env:
             cfg.loader.cache_dir = env["CILIUM_TPU_CACHE_DIR"]
         if env.get("CILIUM_TPU_BANK_ISOLATION", "").lower() in (

@@ -46,7 +46,9 @@ _KERNEL_SHIFT = 58    # 3 bits: kernel impl code
 _VERSION_SHIFT = 61   # 2 bits: word schema version
 WORD_VERSION = 1
 
-#: kernel impl labels ⇄ word codes (0 = unknown/absent)
+#: kernel impl labels ⇄ word codes (0 = unknown/absent). 1 ("legacy",
+#: the unfused engine mode, now gone) stays reserved so words packed
+#: under it still decode
 KERNEL_CODES = {"": 0, "legacy": 1, "dfa-dense": 2, "nfa-bitset": 3,
                 "mixed": 4, "oracle": 5}
 KERNEL_NAMES = {v: k for k, v in KERNEL_CODES.items()}

@@ -28,19 +28,17 @@ http, dns, kafka, generic — resolves in group space inside the one
 fused launch; the precedence/auth/audit assembly stays the shared
 ``_assemble_verdict``.
 
-**Per-bank-shape scan autotuning.** The byte-scan has two
-implementations — the dense-gather DFA (``engine/dfa_kernel.py``) and
-the bitset-NFA "rules-as-lanes" arm (``engine/nfa_kernel.py`` /
-``engine/pallas_nfa.py``, block-diagonal one-hot matmuls on the MXU).
-Which wins is a property of the bank *shape* (DFA state count vs NFA
-position count, class count, backend), so the pick is made per field
-stack at engine staging — heuristically under ``kernel_impl=auto``
-(dense everywhere except TPU banks whose DFA passes 128 states while
-their NFA positions fit one 128-lane tile), measured under
-``kernel_impl=autotune`` — cached process-wide by shape+backend key,
-recorded on the policy's kernel plan and the loader's bank registry,
-and carried across warm restarts through the snapshot. Every arm is
-bit-equal; the autotuner only ever changes *time*.
+**One rule per field stack.** The byte-scan has two arms — the
+dense DFA (``engine/dfa_kernel.py``: the gather, or the MXU kernel of
+``engine/pallas_dfa.py``) and the bitset-NFA "rules-as-lanes" arm
+(``engine/nfa_kernel.py`` / ``engine/pallas_nfa.py``, block-diagonal
+one-hot matmuls on the MXU). Which one a field takes is a property of
+its bank *shape* and the backend, so ``dfa_kernel.pick_arm`` decides
+it once per field stack at engine staging (:func:`plan_for_engine`):
+the NFA arm only on the TPU, for banks whose DFA passed 128 states
+while their positions fit one 128-lane tile; dense everywhere else.
+The plan is recorded on the policy's ``kernel_plan``. Every arm is
+bit-equal; the rule only ever changes *time*.
 
 "One launch" here means one XLA executable and one device dispatch
 per verdict batch: on TPU the Pallas scan kernels are fused into that
@@ -49,7 +47,6 @@ executable alongside the mapstate gather and the group-space resolve.
 
 from __future__ import annotations
 
-import time
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -57,14 +54,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from cilium_tpu.core.flow import L7Type
-from cilium_tpu.engine import nfa_kernel
-from cilium_tpu.runtime.metrics import (
-    KERNEL_AUTOTUNE_PICKS,
-    KERNEL_AUTOTUNE_SECONDS,
-    METRICS,
-)
+from cilium_tpu.engine import dfa_kernel, nfa_kernel
 
-#: scan implementations the autotuner arbitrates between
+#: the two scan arms a field stack can be planned onto
 IMPL_DENSE = "dfa-dense"
 IMPL_NFA = "nfa-bitset"
 
@@ -607,26 +599,22 @@ def _nfa_stack(arrays, prefix: str) -> Dict[str, jax.Array]:
 
 
 def fused_scan_field(arrays, prefix: str, data, lengths, valid,
-                     impl: str = IMPL_DENSE, dfa_impl: str = "gather",
-                     interpret: bool = False,
-                     use_pallas_nfa: bool = False,
+                     impl: str = IMPL_DENSE, interpret: bool = False,
                      want_groups: bool = False):
     """One field's banked scan under the planned impl → flat match
     words [B, NW] (+ bank-ORed group words [B, Gw])."""
-    from cilium_tpu.engine.dfa_kernel import dfa_scan_banked
-
     if impl == IMPL_NFA:
         stacked = _nfa_stack(arrays, prefix)
         if want_groups:
             stacked["nfa_gaccept"] = arrays[f"{prefix}_nfa_gaccept"]
         out = nfa_kernel.nfa_scan_banked(
             stacked, data, lengths, extra_accept=want_groups,
-            use_pallas=use_pallas_nfa, interpret=interpret)
+            interpret=interpret)
     else:
-        out = dfa_scan_banked(
+        out = dfa_kernel.dfa_scan_banked(
             arrays[f"{prefix}_trans"], arrays[f"{prefix}_byteclass"],
             arrays[f"{prefix}_start"], arrays[f"{prefix}_accept"],
-            data, lengths, impl=dfa_impl, interpret=interpret,
+            data, lengths, interpret=interpret,
             extra_accept=(arrays["rp_path_gaccept"] if want_groups
                           else None))
     if want_groups:
@@ -641,9 +629,7 @@ def fused_scan_field(arrays, prefix: str, data, lengths, valid,
 
 
 def fused_verdict_step(arrays, batch, *, impl_plan=(),
-                       dfa_impl: str = "gather",
-                       interpret: bool = False,
-                       use_pallas_nfa: bool = False):
+                       interpret: bool = False):
     """The megakernel: full verdict for one batch in ONE dispatch.
 
     ``impl_plan`` is a static tuple of (field-prefix, impl) picks from
@@ -674,8 +660,7 @@ def fused_verdict_step(arrays, batch, *, impl_plan=(),
     for prefix, field in scan_fields(arrays):
         w, gw = fused_scan_field(
             arrays, prefix, *batch_field(b, field),
-            impl=impls.get(prefix, IMPL_DENSE), dfa_impl=dfa_impl,
-            interpret=interpret, use_pallas_nfa=use_pallas_nfa,
+            impl=impls.get(prefix, IMPL_DENSE), interpret=interpret,
             want_groups=(plan_on and prefix == "path"))
         words.append(w)
         if gw is not None:
@@ -696,180 +681,98 @@ def fused_verdict_step(arrays, batch, *, impl_plan=(),
                               gen_cols=gen_cols)
 
 
-# -------------------------------------------------------------- autotune --
-#: (shape key) → {"impl", "dense_ms", "nfa_ms"} — process-wide, and
-#: snapshotted through the loader's warm-restart state so a restarted
-#: daemon keeps its picks without re-benching
-_AUTOTUNE_CACHE: Dict[tuple, Dict] = {}
-
-
-def autotune_cache_snapshot() -> Dict:
-    return {repr(k): dict(v) for k, v in _AUTOTUNE_CACHE.items()}
-
-
-def autotune_cache_adopt(snap: Optional[Dict]) -> None:
-    import ast
-
-    if not snap:
-        return
-    for k, v in snap.items():
-        try:
-            key = ast.literal_eval(k)
-        except (ValueError, SyntaxError):
-            continue  # foreign snapshot entry: skip, never crash warm restore
-        if isinstance(key, tuple):
-            # ctlint: disable=unbounded-registry  # keyed by bucketed bank shape x backend (finite)
-            _AUTOTUNE_CACHE.setdefault(key, dict(v))
-
-
-def _shape_key(field: str, trans_shape, nfa_shape, L: int) -> tuple:
-    return (field, tuple(trans_shape), tuple(nfa_shape or ()),
-            int(L), jax.default_backend())
-
-
-def _time_scan(fn, reps: int = 3) -> float:
-    out = fn()
-    jax.block_until_ready(out)  # compile excluded from the sample
-    times = []
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn())
-        times.append(time.perf_counter() - t0)
-    times.sort()
-    return times[len(times) // 2]
-
-
-def autotune_field(field: str, arrays: Dict, prefix: str,
-                   nfa_stacked: Optional[Dict], width: int,
-                   interpret: bool, probe_batch: int = 256) -> Dict:
-    """Measure dense vs bitset-NFA on this field's REAL bank tensors
-    over a synthetic batch of the field's width; cached by shape key."""
-    from cilium_tpu.engine.dfa_kernel import dfa_scan_banked
-
-    trans = arrays[f"{prefix}_trans"]
-    key = _shape_key(
-        field, np.shape(trans),
-        np.shape(nfa_stacked["nfa_follow"]) if nfa_stacked else None,
-        width)
-    hit = _AUTOTUNE_CACHE.get(key)
-    if hit is not None:
-        return hit
-    t0 = time.perf_counter()
-    rng = np.random.default_rng(0)
-    data = jnp.asarray(rng.integers(0, 128, size=(probe_batch, width),
-                                    dtype=np.uint8))
-    lengths = jnp.asarray(
-        rng.integers(0, width + 1, size=(probe_batch,)).astype(np.int32))
-    dense_ms = _time_scan(lambda: jax.jit(dfa_scan_banked)(
-        arrays[f"{prefix}_trans"], arrays[f"{prefix}_byteclass"],
-        arrays[f"{prefix}_start"], arrays[f"{prefix}_accept"],
-        data, lengths)) * 1e3
-    if nfa_stacked is None:
-        result = {"impl": IMPL_DENSE, "dense_ms": round(dense_ms, 3),
-                  "nfa_ms": None}
-    else:
-        stacked = {k: jnp.asarray(v) for k, v in nfa_stacked.items()
-                   if k != "nfa_gaccept"}
-        nfa_ms = _time_scan(lambda: jax.jit(
-            lambda s, d, l: nfa_kernel.nfa_scan_banked(
-                s, d, l, interpret=interpret))(
-            stacked, data, lengths)) * 1e3
-        result = {"impl": IMPL_NFA if nfa_ms < dense_ms else IMPL_DENSE,
-                  "dense_ms": round(dense_ms, 3),
-                  "nfa_ms": round(nfa_ms, 3)}
-    _AUTOTUNE_CACHE[key] = result
-    METRICS.observe(KERNEL_AUTOTUNE_SECONDS, time.perf_counter() - t0)
-    METRICS.inc(KERNEL_AUTOTUNE_PICKS,
-                labels={"impl": result["impl"], "field": field})
-    return result
-
-
-def _field_widths(cfg) -> Dict[str, int]:
-    return {"path": max(cfg.http_path_buckets),
-            "method": cfg.http_method_len, "host": cfg.http_host_len,
-            "hdr": 1024, "dns": cfg.dns_name_len,
-            "l7g": getattr(cfg, "l7g_len", 256)}
-
-
-def plan_for_engine(policy, cfg) -> Tuple[
-        Dict[str, str], Dict[str, np.ndarray], Dict[str, Dict]]:
-    """Pick a scan impl per field stack; build the NFA tensors the
-    picks need. Returns ``(impl_plan, extra_arrays, report)`` —
-    ``extra_arrays`` joins the engine's device arrays, ``report``
-    (per-field pick + timings) lands on the policy's kernel plan and
-    the bench lines."""
-    mode = getattr(cfg, "kernel_impl", "auto")
-    degraded = bool(getattr(policy, "bank_quarantined", ()))
+# ------------------------------------------------------------- planning --
+def _matchers(policy) -> Dict[str, object]:
     matchers = {"path": policy.path_matcher,
                 "method": policy.method_matcher,
                 "host": policy.host_matcher,
                 "hdr": policy.header_matcher,
                 "dns": policy.dns_matcher}
     if getattr(policy, "l7g_matcher", None) is not None:
-        # protocol-frontend automaton: autotuned/armed like any field
+        # protocol-frontend automaton: planned like any field
         matchers["l7g"] = policy.l7g_matcher
-    widths = _field_widths(cfg)
+    return matchers
+
+
+def _nfa_tensors(policy, cfg, prefix: str, matcher
+                 ) -> Optional[Dict[str, np.ndarray]]:
+    """The field's stacked bitset-NFA tensors, or None where the arm
+    cannot hold the field: a bank past ``nfa_kernel.MAX_POSITIONS``,
+    or a quarantined build (stale quarantine covers can't be
+    reconstructed from the current pattern set)."""
+    if getattr(policy, "bank_quarantined", ()):
+        return None
+    banks = nfa_kernel.banks_from_dfa(
+        matcher.banked, cfg, case_insensitive=(prefix == "host"))
+    if banks is None:
+        return None
     lane_groups = (policy.resolve_meta or {}).get("lane_groups") \
         if getattr(policy, "resolve_meta", None) is not None else None
+    gacc = None
+    if prefix == "path" and lane_groups is not None:
+        trans_shape = policy.arrays[f"{prefix}_trans"].shape
+        gacc = [_nfa_group_plane(b, i, trans_shape, policy.arrays,
+                                 lane_groups)
+                for i, b in enumerate(banks)]
+    return nfa_kernel.stack_nfa_banks(banks, extra_accept=gacc)
+
+
+def _plan(policy, nfa_for) -> Tuple[
+        Dict[str, str], Dict[str, np.ndarray], Dict[str, Dict]]:
+    """``(impl_plan, extra_arrays, report)`` with each field on the NFA
+    arm where ``nfa_for(prefix, matcher, trans_shape)`` gives its
+    tensors, dense elsewhere."""
     impl_plan: Dict[str, str] = {}
     extra: Dict[str, np.ndarray] = {}
     report: Dict[str, Dict] = {}
-
-    for prefix, matcher in matchers.items():
-        trans = policy.arrays[f"{prefix}_trans"]
-        small_dfa = trans.shape[1] <= 128
-        # only pay the NFA construction when the mode can actually use
-        # it: forced/measured picks always, the heuristic only in its
-        # one preferred regime (TPU + banks past 128 DFA states)
-        want_nfa = (mode in ("autotune", IMPL_NFA)
-                    or (mode == "auto" and not small_dfa
-                        and jax.default_backend() == "tpu"))
-        nfa_banks = None
-        if not degraded and want_nfa:
-            # stale quarantine covers can't be reconstructed from the
-            # current pattern set — the NFA arm sits out degraded builds
-            nfa_banks = nfa_kernel.banks_from_dfa(
-                matcher.banked, cfg,
-                case_insensitive=(prefix == "host"))
-        nfa_stacked = None
-        if nfa_banks is not None:
-            gacc = None
-            if prefix == "path" and lane_groups is not None:
-                gacc = [_nfa_group_plane(b, i, trans.shape,
-                                         policy.arrays, lane_groups)
-                        for i, b in enumerate(nfa_banks)]
-            nfa_stacked = nfa_kernel.stack_nfa_banks(
-                nfa_banks, extra_accept=gacc)
-        if mode == IMPL_NFA and nfa_stacked is not None:
-            pick = {"impl": IMPL_NFA, "dense_ms": None, "nfa_ms": None}
-        elif mode == "autotune":
-            pick = autotune_field(prefix, policy.arrays, prefix,
-                                  nfa_stacked, widths[prefix],
-                                  interpret=False)
-        elif mode == "auto" and jax.default_backend() == "tpu" \
-                and not small_dfa and nfa_stacked is not None:
-            # the one regime where the heuristic prefers the NFA arm
-            # without measuring: the DFA passed 128 states but the
-            # positions fit one tile (not re-timed against the dense
-            # MXU kernel since it took banks past 128 states)
-            pick = {"impl": IMPL_NFA, "dense_ms": None, "nfa_ms": None}
-        else:
-            pick = {"impl": IMPL_DENSE, "dense_ms": None,
-                    "nfa_ms": None}
-        impl = pick["impl"]
-        if impl == IMPL_NFA and nfa_stacked is None:
-            impl = IMPL_DENSE  # forced arm, ineligible bank → dense
-        if impl == IMPL_NFA:
-            for k, v in nfa_stacked.items():
-                extra[f"{prefix}_{k}"] = v
-        impl_plan[prefix] = impl
-        report[prefix] = {**pick, "impl": impl,
-                          "banks": int(trans.shape[0]),
-                          "dfa_states": int(trans.shape[1]),
+    for prefix, matcher in _matchers(policy).items():
+        trans_shape = policy.arrays[f"{prefix}_trans"].shape
+        nfa = nfa_for(prefix, matcher, trans_shape)
+        impl_plan[prefix] = IMPL_DENSE if nfa is None else IMPL_NFA
+        for k, v in (nfa or {}).items():
+            extra[f"{prefix}_{k}"] = v
+        report[prefix] = {"impl": impl_plan[prefix],
+                          "banks": int(trans_shape[0]),
+                          "dfa_states": int(trans_shape[1]),
                           "nfa_positions": (
-                              int(nfa_stacked["nfa_follow"].shape[1])
-                              if nfa_stacked is not None else None)}
+                              int(nfa["nfa_follow"].shape[1])
+                              if nfa is not None else None)}
     return impl_plan, extra, report
+
+
+def plan_for_engine(policy, cfg) -> Tuple[
+        Dict[str, str], Dict[str, np.ndarray], Dict[str, Dict]]:
+    """Plan each field stack onto the arm ``dfa_kernel.pick_arm``
+    gives it on this process's backend, and build the NFA tensors the
+    plan needs. Returns ``(impl_plan, extra_arrays, report)`` —
+    ``extra_arrays`` joins the engine's device arrays, ``report``
+    (per-field pick and shapes) lands on the engine's
+    ``kernel_report``."""
+    backend = jax.default_backend()
+
+    def nfa_for(prefix, matcher, trans_shape):
+        built = []
+
+        def nfa_fits() -> bool:
+            built.append(_nfa_tensors(policy, cfg, prefix, matcher))
+            return built[0] is not None
+
+        _nb, states, classes = trans_shape
+        arm = dfa_kernel.pick_arm(backend, states, classes, 0, nfa_fits)
+        return built[0] if arm == "nfa" else None
+
+    return _plan(policy, nfa_for)
+
+
+def nfa_plan(policy, cfg) -> Tuple[
+        Dict[str, str], Dict[str, np.ndarray], Dict[str, Dict]]:
+    """Every field stack the bitset-NFA arm can hold, planned onto it —
+    the explicit plan that runs the arm where the rule would not pick
+    it (the rule gives it only TPU banks past 128 DFA states): the
+    differential tests on the CPU, and the chip smoke's Pallas NFA
+    phase. Stage it with :meth:`VerdictEngine.stage_plan`."""
+    return _plan(policy, lambda prefix, matcher, _shape: _nfa_tensors(
+        policy, cfg, prefix, matcher))
 
 
 def _nfa_group_plane(bank, bank_idx: int, trans_shape,

@@ -26,9 +26,10 @@ Why it earns a place next to the dense DFA:
   positions.
 * **Data-oblivious** — two fixed-shape matmuls per byte, the RE2-style
   input-independent timing guarantee, on the MXU instead of the VPU.
-* On CPU backends the matmul costs more than the gather; the
-  per-bank-shape autotuner (``engine/megakernel.py``) measures both
-  and records the pick, so the arm only serves where it wins.
+* On CPU backends the matmul costs more than the gather, so the arm
+  serves only on the TPU, where ``dfa_kernel.pick_arm`` gives it the
+  banks whose DFA passed 128 states while their positions fit one
+  tile.
 
 Exactness: all matrices are 0/1; products accumulate counts ≤ P ≤ 128,
 exact in f32 (``preferred_element_type`` pinned); thresholding ``> 0``
@@ -51,8 +52,8 @@ from cilium_tpu.policy.compiler.dfa import _byte_classes
 from cilium_tpu.policy.compiler.nfa import build_nfa, eps_closure
 
 #: position budget per bank: one MXU tile — the Pallas kernel's hard
-#: cap, and the eligibility bound the autotuner respects on every
-#: backend (past it the follow matmul outgrows its tile anyway)
+#: cap, and the arm's eligibility bound on every backend (past it
+#: the follow matmul outgrows its tile anyway)
 MAX_POSITIONS = 128
 
 
@@ -231,15 +232,17 @@ def nfa_scan_banked(
     data: jax.Array,        # [B, L] uint8/int32
     lengths: jax.Array,     # [B]
     extra_accept: bool = False,
-    use_pallas: bool = False,
     interpret: bool = False,
 ):
     """All banks over one batch → accept words ``[B, NB, W]`` uint32
     (+ group words ``[B, NB, Wg]`` when ``extra_accept`` and the stack
     carries a ``nfa_gaccept`` plane). Same contract as
     ``dfa_scan_banked`` — the two arms are interchangeable per bank
-    shape, which is what the autotuner relies on."""
-    if use_pallas:
+    shape, which is what ``dfa_kernel.pick_arm`` relies on. The
+    positions advance in the Pallas kernel on the TPU (the backend
+    reading the rule makes) or, with ``interpret``, in its
+    interpreter; elsewhere in plain XLA."""
+    if interpret or jax.default_backend() == "tpu":
         from cilium_tpu.engine.pallas_nfa import nfa_finals_pallas
 
         finals = nfa_finals_pallas(

@@ -5,24 +5,19 @@ Why a hand-written kernel: the dense arm's scalar gather
 shape amortizes (~8.8 ns a lookup on a v5e, whatever the state count),
 while this kernel's step is a dense contraction whose cost is
 shape-only (~2.0 ns a lookup at 2,048 states, TILE 1024; PERF.md §6).
-That buys two things:
-
-* throughput on wide banks: under the default pick
-  ``dfa_kernel.pick_arm`` sends here, on the TPU, every bank stack of
-  more than 128 padded states over a batch that fills a tile — the
-  capture string-table scan of the 1k-rule path stack (7 banks × 2,048
-  states);
-* the RE2-style linear-time, *input-independent* timing guarantee the
-  reference relies on (SURVEY.md §2.2), for deployments where verdict
-  latency must not leak rule or payload structure: the explicit
-  ``CILIUM_TPU_DFA_IMPL=pallas`` pick runs every bank here. Nothing in
-  the step gathers: the byte → class lookup is a contraction too.
+That buys throughput on wide banks: ``dfa_kernel.pick_arm`` sends
+here, on the TPU, every dense bank stack of more than 128 padded
+states over a batch that fills a tile — the capture string-table scan
+of the 1k-rule path stack (7 banks × 2,048 states). Nothing in the
+step gathers: the byte → class lookup is a contraction too, so the
+step's timing is input-independent (the RE2-style guarantee of
+SURVEY.md §2.2) for the stacks it takes.
 
 Layout: flows ride the lane axis (TILE lanes), the state and class
 axes ride sublanes, and each step is
 
-    rows = planes @ onehot(state)        # [2·KP,SP] @ [SP,TILE] on MXU
-    pick = cmap @ onehot(byte)           # [KP,256] @ [256,TILE] on MXU
+    rows = planes @ one_hot(state)       # [2·KP,SP] @ [SP,TILE] on MXU
+    pick = cmap @ one_hot(byte)          # [KP,256] @ [256,TILE] on MXU
     hi, lo = Σ_k rows ⊙ pick             # VPU column select, per plane
     next = hi·64 + lo  (held where t ≥ length)
 
@@ -57,7 +52,7 @@ TILE = 1024
 #: TILE, the planes stay exact, and the kernel still beats the gather
 #: there on a v5e (6.1 against 8.8 ns a lookup, PERF.md §6)
 MAX_STATES = 8192
-#: byte classes the auto rule sends here: the stacked hi/lo planes
+#: byte classes the rule sends here: the stacked hi/lo planes
 #: fill at most one 128-row MXU pass (timed at 28 classes only)
 MAX_CLASSES = 64
 LO_BITS = 6         # transition id = hi << LO_BITS | lo

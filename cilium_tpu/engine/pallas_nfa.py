@@ -3,7 +3,7 @@
 The MXU-resident face of ``engine/nfa_kernel.py``: the per-byte
 position advance
 
-    D' = ((Followᵀ · D) > 0) ⊙ (ClassAccept · onehot(class))
+    D' = ((Followᵀ · D) > 0) ⊙ (ClassAccept · one_hot(class))
 
 is two matmuls per byte — the block-structured follow advance and the
 class-acceptance plane select — with the position bitset ``D`` living

@@ -243,8 +243,7 @@ def _record(report: Dict, reps: int) -> None:
                                 labels={"phase": phase})
 
 
-def _impl_scan(arrays, batch, impl_plan, wanted: str,
-               dfa_impl: str, use_pallas_nfa: bool):
+def _impl_scan(arrays, batch, impl_plan, wanted: str):
     """Scan only the fields the engine's kernel plan runs through
     ``wanted`` — the per-impl attribution lanes (dfa-dense /
     nfa-bitset phase labels)."""
@@ -257,15 +256,14 @@ def _impl_scan(arrays, batch, impl_plan, wanted: str,
         if impls.get(prefix, "dfa-dense") != wanted:
             continue
         w, _ = fused_scan_field(
-            arrays, prefix, *batch_field(b, field), impl=wanted,
-            dfa_impl=dfa_impl, use_pallas_nfa=use_pallas_nfa)
+            arrays, prefix, *batch_field(b, field), impl=wanted)
         out.append(w)
     return tuple(out)
 
 
 #: jitted once at module scope — per-call wrapping would churn the jit
 #: cache (the recompile-hazard rule's own lesson)
-_IMPL_SCAN = jax.jit(_impl_scan, static_argnums=(2, 3, 4, 5))
+_IMPL_SCAN = jax.jit(_impl_scan, static_argnums=(2, 3))
 
 
 class EnginePhaseProbe:
@@ -278,8 +276,8 @@ class EnginePhaseProbe:
         self._ms = jax.jit(_live_mapstate)
         self._scan = jax.jit(_live_scan)
         self._resolve = jax.jit(_live_resolve)
-        # the engine's STAGED step (the fused megakernel unless the
-        # engine was built legacy) — the wall the decomposition covers
+        # the engine's STAGED step (the fused megakernel) — the wall
+        # the decomposition covers
         self._full = engine._step
         self._impl_plan = tuple(sorted(
             getattr(engine, "impl_plan", {}).items()))
@@ -345,10 +343,7 @@ class EnginePhaseProbe:
         for impl in sorted({v for _, v in self._impl_plan} or
                            {"dfa-dense"}):
             impl_s, _, _ = _timed(
-                lambda: _IMPL_SCAN(
-                    arrays, batch, self._impl_plan, impl,
-                    getattr(self.engine, "_dfa_impl", "gather"),
-                    getattr(self.engine, "_pallas", False)),
+                lambda: _IMPL_SCAN(arrays, batch, self._impl_plan, impl),
                 reps, site="engine-impl-scan")
             phases_ms[impl] = round(impl_s * 1e3, 3)
         attributed = (ms_s + scan_s + res_s) * 1e3

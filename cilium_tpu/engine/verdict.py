@@ -228,8 +228,8 @@ class CompiledPolicy:
     #: grouping degenerated (fused step falls back to legacy resolve).
     resolve_meta: Optional[Dict] = None
     #: field → scan-impl pick ("dfa-dense" / "nfa-bitset"), written at
-    #: engine staging by the per-bank-shape autotuner; rides the
-    #: policy object into bank_status and the bench lines
+    #: engine staging from ``dfa_kernel.pick_arm`` (the engine's
+    #: ``impl_plan``)
     kernel_plan: Dict[str, str] = dataclasses.field(default_factory=dict)
 
     @classmethod
@@ -1255,7 +1255,7 @@ _TABLE_FIELDS = (("path", "path"), ("method", "method"),
 def _stage_tables_step(arrays: Dict[str, jax.Array],
                        tables: Dict[str, tuple],
                        earlier: Dict[str, tuple],
-                       impl: str = "gather"
+                       impl: Optional[str] = None
                        ) -> Dict[str, Dict[str, jax.Array]]:
     """One row piece of the per-field table scans as ONE traced
     program (:func:`stage_capture_tables`): each field in ``tables``
@@ -1276,8 +1276,8 @@ def _stage_tables_step(arrays: Dict[str, jax.Array],
     final states, bank-ORed into the ``"path_groups"`` table the fused
     capture resolve gathers. The frontend l7g table (present only when
     the policy carries its automaton) scans exactly like the five
-    string fields. ``impl`` is trace-static (the engine resolves it at
-    staging; see dfa_kernel.resolve_impl)."""
+    string fields. Each stack takes the arm ``dfa_kernel.scan_arm``
+    gives it, or ``impl`` where a test names the arm."""
     out: Dict[str, Dict[str, jax.Array]] = {}
     for field, prefix in _TABLE_FIELDS + (("l7g", "l7g"),):
         if field not in tables:
@@ -1306,11 +1306,11 @@ def _stage_tables_step(arrays: Dict[str, jax.Array],
     return out
 
 
-@functools.lru_cache(maxsize=8)
-def _stage_tables_jit(impl: str):
-    """One jitted staging program per static impl — the env pick
-    resolves on the host, never under trace. Its module is named
-    ``jit__unknown`` (a partial has no name), which the benchmark's
+@functools.lru_cache(maxsize=4)
+def _stage_tables_jit(impl: Optional[str] = None):
+    """The jitted staging program: the rule's arms (``impl`` None), or
+    an arm a test names. Its module is named ``jit__unknown`` (a
+    partial has no name), which the benchmark's
     ``megakernel_roofline`` counts as the scan's device time."""
     return jax.jit(functools.partial(_stage_tables_step, impl=impl))
 
@@ -1352,8 +1352,7 @@ def stage_capture_tables(engine: "VerdictEngine",
     fields = [field for field, _ in _TABLE_FIELDS]
     if "l7g" in rows and "l7g_trans" in engine._arrays:
         fields.append("l7g")
-    impl = getattr(engine, "_dfa_impl", "gather")
-    step = _stage_tables_jit(impl)
+    step = _stage_tables_jit()
     prefix_of = dict(_TABLE_FIELDS + (("l7g", "l7g"),))
     P = TABLE_PIECE_ROWS
     table_words: Dict[str, jax.Array] = {}
@@ -1368,7 +1367,8 @@ def stage_capture_tables(engine: "VerdictEngine",
             dev = jax.device_put(piece, engine.device)
             out = step(engine._arrays, dev, earlier)
         for f in piece:
-            arm = scan_arm(impl, engine._arrays[f"{prefix_of[f]}_trans"].shape,
+            arm = scan_arm(None,
+                           engine._arrays[f"{prefix_of[f]}_trans"].shape,
                            len(piece[f][0]))
             _METRICS.inc(_CAPTURE_TABLE_PIECES,
                          labels={"field": f, "arm": arm})
@@ -2006,6 +2006,11 @@ def verdict_step(arrays: Dict[str, jax.Array], batch: Dict[str, jax.Array]
         gen_cols=(batch["gen_proto"], batch["gen_pairs"]))
 
 
+#: :func:`verdict_step` jitted once for the process: the single-device
+#: reference the DST harness checks its sharded lanes against
+VERDICT_STEP = jax.jit(verdict_step)
+
+
 def batch_field(batch: Dict[str, jax.Array], name: str):
     return (batch[f"{name}_data"], batch[f"{name}_len"],
             batch[f"{name}_valid"])
@@ -2072,59 +2077,53 @@ class _StagePhase:
 class VerdictEngine:
     """Jitted wrapper around the verdict step for a CompiledPolicy.
 
-    By default the step is the fused megakernel
+    The step is the fused megakernel
     (``engine/megakernel.fused_verdict_step``): one device dispatch
     for mapstate gather + byte-scans + factored priority resolve,
-    with the scan impl picked per bank shape at staging and recorded
-    on ``policy.kernel_plan``. ``cfg.kernel_impl="legacy"`` (or a
-    policy whose resolve plan degenerated) reverts to the unfused
-    :func:`verdict_step` — bit-equal either way."""
+    with each field's scan arm planned at staging by
+    ``dfa_kernel.pick_arm`` and recorded on ``policy.kernel_plan``
+    (a policy whose resolve plan degenerated keeps the unfused
+    resolve inside the same dispatch)."""
 
     def __init__(self, policy: CompiledPolicy, device=None,
                  cfg: Optional[EngineConfig] = None):
         from cilium_tpu.engine import megakernel as _mk
-        from cilium_tpu.engine.dfa_kernel import resolve_impl
 
         self.policy = policy
         self.device = device
         self.cfg = cfg or EngineConfig()
-        #: trace-static scan choices, resolved ONCE here on the host
-        #: (never under trace — the ctlint jit-purity contract)
-        self._dfa_impl = resolve_impl()
-        #: Pallas arms compile for the TPU only: on any other backend
-        #: the engine picks none (no silent interpreter)
-        self._pallas = jax.default_backend() == "tpu"
         self._arrays = {
             k: jax.device_put(v, device) for k, v in policy.arrays.items()
         }
         #: True when some staged entry demands authentication — when
         #: False, callers skip staging the authed-pairs table
         self.needs_auth = bool(np.any(policy.arrays["ms_auth"]))
-        #: field → scan impl of the staged step ({} on the legacy path)
-        self.impl_plan: Dict[str, str] = {}
-        #: per-field autotune report (impl, timings, shapes)
-        self.kernel_report: Dict[str, Dict] = {}
-        mode = getattr(self.cfg, "kernel_impl", "auto")
-        if mode != "legacy":
-            impl_plan, extra, report = _mk.plan_for_engine(
-                policy, self.cfg)
-            for k, v in extra.items():
-                self._arrays[k] = jax.device_put(v, device)
-            self.impl_plan = impl_plan
-            self.kernel_report = report
-            policy.kernel_plan = dict(impl_plan)
-            self._step = jax.jit(functools.partial(
-                _mk.fused_verdict_step,
-                impl_plan=tuple(sorted(impl_plan.items())),
-                dfa_impl=self._dfa_impl,
-                use_pallas_nfa=self._pallas))
-        else:
-            self._step = jax.jit(verdict_step)
-        #: layout-tuple → jitted blob step (the layout is static per
-        #: config; distinct layouts are distinct compiles)
-        self._blob_steps: Dict[tuple, object] = {}
+        self.stage_plan(_mk.plan_for_engine(policy, self.cfg))
         #: lazily-built host-side attribution decoder (provenance)
         self._attribution = None
+
+    def stage_plan(self, plan) -> None:
+        """Stage the fused step for ``plan``, an ``(impl_plan,
+        extra_arrays, report)`` triple from
+        ``megakernel.plan_for_engine`` (the rule's plan, staged at
+        construction) or ``megakernel.nfa_plan``."""
+        from cilium_tpu.engine import megakernel as _mk
+
+        impl_plan, extra, report = plan
+        for k, v in extra.items():
+            self._arrays[k] = jax.device_put(v, self.device)
+        #: field → scan arm of the staged step
+        self.impl_plan: Dict[str, str] = dict(impl_plan)
+        #: per-field plan report (arm, bank count, DFA states, NFA
+        #: positions)
+        self.kernel_report: Dict[str, Dict] = report
+        self.policy.kernel_plan = dict(impl_plan)
+        self._step = jax.jit(functools.partial(
+            _mk.fused_verdict_step,
+            impl_plan=tuple(sorted(impl_plan.items()))))
+        #: layout-tuple → jitted blob step over ``_step`` (the layout
+        #: is static per config; distinct layouts are distinct compiles)
+        self._blob_steps: Dict[tuple, object] = {}
 
     @property
     def attribution(self):

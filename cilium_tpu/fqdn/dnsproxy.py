@@ -110,7 +110,6 @@ class DNSProxy:
                     st = self._get_banked(key, srcs)
                     from cilium_tpu.engine.dfa_kernel import (
                         dfa_scan_banked,
-                        resolve_impl,
                     )
 
                     data = np.zeros((len(sanitized), 256),
@@ -121,12 +120,9 @@ class DNSProxy:
                         data[i, : len(bs)] = np.frombuffer(
                             bs, dtype=np.uint8)
                         lengths[i] = len(bs)
-                    # host-side eager call: the env pick resolves HERE,
-                    # not under trace (dfa_kernel.resolve_impl contract)
                     words = np.asarray(dfa_scan_banked(
                         st["trans"], st["byteclass"], st["start"],
-                        st["accept"], data, lengths,
-                        impl=resolve_impl()))
+                        st["accept"], data, lengths))
                     return (words.reshape(len(sanitized), -1)
                             .any(axis=1) != 0)
             except Exception:  # noqa: BLE001 — device sick: degrade

@@ -6,16 +6,15 @@ transition-table *state* dimension over a mesh axis, with a ``psum``
 combining the per-shard partial contributions — the classic
 contracting-dimension-sharded matmul.
 
-The step uses the one-hot matmul formulation of the DFA transition
-(engine/dfa_kernel.py "onehot" impl): with the current state one-hot
+The step uses the one-hot matmul formulation of the DFA transition:
+with the current state one-hot
 ``oh[B, S]`` and transition table ``T[S, K]``, the next-state row is
 ``oh @ T``. Sharding ``S`` gives each device a slice ``T[S/n, K]`` and
 the *partial* one-hot for its state range (all-zero rows when the
 current state lives on another shard); the local matmul produces a
 partial ``[B, K]`` contribution and ``lax.psum`` restores the exact row
 (each one-hot row has exactly one nonzero, so the sum has exactly one
-contributing term). Like the "onehot" impl in dfa_kernel.py, state ids
-ride through float32, exact only below 2^24 — enforced with a hard
+contributing term). State ids ride through float32, exact only below 2^24 — enforced with a hard
 check (``MAX_TP_STATES``), not a silent wrap. Accept-word extraction is
 sharded the same way, byte-plane by byte-plane.
 

@@ -307,20 +307,14 @@ class BankRegistry:
         self.quarantined_serves = 0
         self.pending_serves = 0
         self.evictions = 0
-        #: bank key → scan-impl pick ("dfa-dense" / "nfa-bitset") the
-        #: megakernel autotuner recorded at staging — content-addressed
-        #: banks carry their kernel choice across regenerations (the
-        #: loader writes it after every successful stage; pruned to
-        #: live groups so it can't outgrow the bounded store)
-        self.kernel_picks: Dict[str, str] = {}
         #: pattern → tenant namespace (None = tenant-blind): the
         #: loader installs it from the TenantMap before a regeneration
         #: so the partition, the bank keys, and the queue's fair-share
         #: attribution all see the same namespace split (ISSUE 20)
         self.namer = None
         #: bank key → tenant namespace, the attribution index the DST
-        #: tenant-isolation invariant reads; pruned alongside
-        #: kernel_picks so it can't outgrow the bounded store
+        #: tenant-isolation invariant reads; pruned to live groups so
+        #: it can't outgrow the bounded store
         # ctlint: disable=unbounded-registry  # pruned with the cover index
         self.namespaces: Dict[str, str] = {}
 
@@ -386,9 +380,6 @@ class BankRegistry:
                         live |= set(s.groups)
                 self._cover = {ck: k for ck, k in self._cover.items()
                                if k in live}
-                self.kernel_picks = {
-                    k: v for k, v in self.kernel_picks.items()
-                    if k in live}
                 self.namespaces = {
                     k: v for k, v in self.namespaces.items()
                     if k in live}
@@ -776,7 +767,6 @@ class BankRegistry:
             "pending": len(self._pending_keys),
             "pending_serves": self.pending_serves,
             "evictions": self.evictions,
-            "kernel_picks": dict(self.kernel_picks),
         }
         if self.queue is not None:
             out["queue"] = self.queue.status()

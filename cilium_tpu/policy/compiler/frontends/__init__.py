@@ -5,7 +5,7 @@ The engine historically spoke exactly four L7 families (http / kafka /
 dns / generic) while ``proxylib/`` carried cassandra, memcached, and
 r2d2 as host-side ``OnData`` state machines whose policy decisions
 never touched the banked byte-scan. Since the megakernel's factored
-resolve, the per-bank autotuner, and the bank-reference memo
+resolve, the per-bank-shape scan arm pick, and the bank-reference memo
 invalidation are protocol-agnostic, a new protocol is a *compiler
 frontend*, not an engine fork (SURVEY §2.2 calls the r2d2/testparsers
 shape "the didactic template"; Hyperflex's SIMD-DFA framing says the

@@ -51,7 +51,6 @@ regression back to the schedule that exposed it via the
 from __future__ import annotations
 
 import argparse
-import functools
 import hashlib
 import json
 import os
@@ -62,16 +61,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 from cilium_tpu.runtime import faults, simclock
 
-
-@functools.lru_cache(maxsize=1)
-def _ref_step():
-    """Memoized single-device reference step for the multichip arm —
-    one jit wrapper for the process (ctlint recompile-hazard)."""
-    import jax
-
-    from cilium_tpu.engine.verdict import verdict_step
-
-    return jax.jit(verdict_step)
 
 #: schedule format epoch, stamped on every trace + shrunken case
 SCHEDULE_FORMAT = 1
@@ -875,6 +864,7 @@ class DSTWorld:
 
         from cilium_tpu.core.flow import Verdict
         from cilium_tpu.engine.verdict import (
+            VERDICT_STEP,
             encode_flows,
             flowbatch_to_host_dict,
         )
@@ -897,7 +887,7 @@ class DSTWorld:
         try:
             host = flowbatch_to_host_dict(encode_flows(
                 padded, policy.kafka_interns, self.cfg.engine))
-            ref = _ref_step()(
+            ref = VERDICT_STEP(
                 {k: jnp.asarray(v) for k, v in policy.arrays.items()},
                 {k: jnp.asarray(v) for k, v in host.items()})
             mesh = make_mesh((n,), ("data",), devs[:n])

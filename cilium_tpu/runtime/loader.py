@@ -609,7 +609,6 @@ class Loader:
                     TRACER.span("policy.stage", cache_hit=cached):
                 engine = VerdictEngine(policy, device=self.device,
                                        cfg=self.config.engine)
-        self._record_kernel_plan(policy, engine)
         # serving frontend-rule counts per proto (the ISSUE-15 family
         # surface; zeroed protos simply stop being reported)
         fe_counts: Dict[str, int] = {}
@@ -708,22 +707,6 @@ class Loader:
                                  identity_families=families,
                                  identity_family_ports=family_ports)
 
-    def _record_kernel_plan(self, policy, engine) -> None:
-        """Push the staged engine's per-bank kernel picks into the
-        bank registry (content-addressed banks carry their kernel
-        choice across regenerations) and onto the serving plan the
-        `status` op exposes."""
-        picks = dict(getattr(engine, "impl_plan", {}) or {})
-        self._kernel_plan = picks
-        if self.bank_registry is None or not picks:
-            return
-        field_of_prefix = {"path": "path", "method": "method",
-                           "host": "host", "hdr": "hdr", "dns": "dns"}
-        for prefix, impl in picks.items():
-            field = field_of_prefix.get(prefix, prefix)
-            for key in getattr(policy, "bank_plan", {}).get(field, ()):
-                self.bank_registry.kernel_picks[key] = impl
-
     def _update_protected(self) -> None:
         """Keep the byte-bounded artifact cache's eviction-exempt set
         pointing at what we actually serve: the active compiled
@@ -757,10 +740,11 @@ class Loader:
         with self._lock:
             degraded = self._degraded
             plan = {f: len(k) for f, k in self._bank_plan.items()}
+            kernel_plan = dict(getattr(self._engine, "impl_plan", {}))
         out: Dict[str, object] = {"enabled": True, "degraded": degraded}
         out.update(self.bank_registry.status())
         out["plan"] = plan
-        out["kernel_plan"] = dict(getattr(self, "_kernel_plan", {}))
+        out["kernel_plan"] = kernel_plan
         out["fp_store"] = self._fp_store.status()
         return out
 
@@ -904,10 +888,6 @@ class Loader:
             key = self._last_artifact_key
         if engine is None or not self._cache.enable:
             return False
-        from cilium_tpu.engine.megakernel import (
-            autotune_cache_snapshot,
-        )
-
         self._cache.put(WARM_STATE_KEY, {
             "format": 1,
             "revision": revision,
@@ -915,10 +895,6 @@ class Loader:
             "per_identity": per_identity,
             "offload": bool(self.config.enable_tpu_offload),
             "audit": bool(self.config.policy_audit_mode),
-            # per-bank-shape kernel picks survive the restart: the
-            # restaged engine re-plans against a warm autotune cache
-            # instead of re-benching every shape
-            "kernel_autotune": autotune_cache_snapshot(),
         })
         return True
 
@@ -934,9 +910,6 @@ class Loader:
         state = self._cache.get(WARM_STATE_KEY)
         if not isinstance(state, dict) or state.get("format") != 1:
             return False
-        from cilium_tpu.engine.megakernel import autotune_cache_adopt
-
-        autotune_cache_adopt(state.get("kernel_autotune"))
         try:
             revision = int(state["revision"])
             per_identity = state["per_identity"]
@@ -980,7 +953,6 @@ class Loader:
                         engine = VerdictEngine(
                             policy, device=self.device,
                             cfg=self.config.engine)
-                self._record_kernel_plan(policy, engine)
                 # a real fingerprint change (or an unknown serving
                 # state): hand memo owners the identity-scoped delta
                 # when the serving fingerprints can vouch for it

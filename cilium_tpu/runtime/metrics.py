@@ -264,14 +264,6 @@ CANARY_COMMITS = "cilium_tpu_canary_commits_total"
 #: gauge: observed verdict-diff fraction of the active canary
 CANARY_DIFF_FRACTION = "cilium_tpu_canary_diff_fraction"
 
-# -- megakernel scan autotuner (engine/megakernel.py): dense-DFA vs
-# bitset-NFA measured per bank shape at engine staging
-#: autotuner decisions, by winning impl and field (cache misses only —
-#: a shape-key hit re-serves the recorded pick without re-benching)
-KERNEL_AUTOTUNE_PICKS = "cilium_tpu_kernel_autotune_picks_total"
-#: wall seconds spent measuring one bank shape (both arms)
-KERNEL_AUTOTUNE_SECONDS = "cilium_tpu_kernel_autotune_seconds"
-
 #: latency-shaped default boundaries (seconds; the Prometheus client
 #: defaults) — covers every ``*_seconds`` series we emit
 DEFAULT_BUCKETS: Tuple[float, ...] = (
@@ -797,12 +789,6 @@ METRICS.describe(FP_CACHE_EVICTIONS,
 METRICS.describe(ARTIFACT_CACHE_EVICTIONS,
                  "artifact-cache entries evicted by the byte-bound "
                  "LRU (serving + warm keys protected)")
-METRICS.describe(KERNEL_AUTOTUNE_PICKS,
-                 "megakernel scan-impl autotune decisions, by impl "
-                 "and field")
-METRICS.describe(KERNEL_AUTOTUNE_SECONDS,
-                 "seconds measuring dense vs bitset-NFA for one bank "
-                 "shape")
 METRICS.describe(SERVE_RING_OCCUPANCY,
                  "stream slots currently leased in the verdict ring")
 METRICS.describe(SERVE_LEASE_GRANTS,

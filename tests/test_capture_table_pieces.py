@@ -52,7 +52,7 @@ def _featurizer(engine, cfg, l7, offsets, blob):
 def _whole_scan(engine, feat):
     """Each whole table through one program: staging as one piece."""
     tables = {f: feat.tables[f] for f, _ in V._TABLE_FIELDS}
-    step = V._stage_tables_jit(getattr(engine, "_dfa_impl", "gather"))
+    step = V._stage_tables_jit()
     out = step(engine._arrays, jax.device_put(tables, engine.device), {})
     return {k: w for words in out.values() for k, w in words.items()}
 

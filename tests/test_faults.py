@@ -1400,6 +1400,37 @@ def test_warm_restore_same_artifact_keeps_memo(tmp_path):
         "unique-row device buffer was re-staged"
 
 
+def test_warm_restore_ignores_an_old_kernel_autotune_key(tmp_path):
+    """A format-1 warm snapshot written while the engine still had a
+    staging autotuner carries its cache under ``kernel_autotune``. The
+    key means nothing now: the snapshot still restores, and the
+    restarted loader serves the snapshot's revision with the same
+    verdicts."""
+    from cilium_tpu.runtime.loader import WARM_STATE_KEY
+
+    cfg = Config()
+    cfg.enable_tpu_offload = True
+    cfg.loader.cache_dir = str(tmp_path / "cache")
+    loader = Loader(cfg)
+    per1, db, web = _tiny_policy(5432)
+    loader.regenerate(per1, revision=4)
+    flows = [_flow(web, db, 5432), _flow(web, db, 6000)]
+    golden = [int(v) for v in loader.engine.verdict_flows(flows)["verdict"]]
+    assert loader.snapshot_warm() is True
+    state = dict(loader._cache.get(WARM_STATE_KEY))
+    assert "kernel_autotune" not in state
+    state["kernel_autotune"] = {
+        "('path', (7, 2048, 28), (), 256, 'tpu')": {
+            "impl": "dfa-dense", "dense_ms": 1.5, "nfa_ms": None}}
+    loader._cache.put(WARM_STATE_KEY, state)
+
+    loader2 = Loader(cfg)
+    assert loader2.restore_warm() is True
+    assert loader2.revision == 4
+    got = loader2.engine.verdict_flows(flows)["verdict"]
+    assert [int(v) for v in got] == golden
+
+
 # ---------------------------------------------------------------------------
 # ISSUE 20: tenant.quota + canary.dispatch fault points
 

@@ -34,8 +34,7 @@ def test_sp_equals_sequential():
     bank, data, lengths = _setup()
     trans = jnp.asarray(bank.trans)
     bc = jnp.asarray(bank.byteclass)
-    seq = dfa_scan(trans, bc, jnp.int32(bank.start), data, lengths,
-                   impl="gather")
+    seq = dfa_scan(trans, bc, jnp.int32(bank.start), data, lengths)
     sp = payload_scan_sp(trans, bc, jnp.int32(bank.start), data, lengths,
                          block=128)
     np.testing.assert_array_equal(np.asarray(seq), np.asarray(sp))
@@ -49,8 +48,7 @@ def test_cp_ring_equals_sequential(n_dev):
     bank, data, lengths = _setup(L=2048)
     trans = jnp.asarray(bank.trans)
     bc = jnp.asarray(bank.byteclass)
-    seq = dfa_scan(trans, bc, jnp.int32(bank.start), data, lengths,
-                   impl="gather")
+    seq = dfa_scan(trans, bc, jnp.int32(bank.start), data, lengths)
     mesh = make_mesh((n_dev,), ("seq",), jax.devices()[:n_dev])
     cp = payload_scan_cp(mesh, trans, bc, bank.start, data, lengths,
                          seq_axis="seq", block=64)
@@ -61,8 +59,7 @@ def test_sp_odd_lengths_and_padding():
     bank, data, lengths = _setup(L=1000)  # not a multiple of block
     trans = jnp.asarray(bank.trans)
     bc = jnp.asarray(bank.byteclass)
-    seq = dfa_scan(trans, bc, jnp.int32(bank.start), data, lengths,
-                   impl="gather")
+    seq = dfa_scan(trans, bc, jnp.int32(bank.start), data, lengths)
     sp = payload_scan_sp(trans, bc, jnp.int32(bank.start), data, lengths,
                          block=256)
     np.testing.assert_array_equal(np.asarray(seq), np.asarray(sp))

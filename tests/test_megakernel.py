@@ -1,12 +1,13 @@
 """Megakernel differential suite (ISSUE 9 acceptance).
 
 The fused verdict step (`engine/megakernel.py`) must be BIT-EQUAL to
-the legacy three-op path on every output lane, for every scan arm the
-autotuner can pick — over the golden 5000-flow corpus, curated edge
-policies (LOG header matches, dead secret-backed rules, multi-ruleset
-membership), and hypothesis-random rule banks/payloads. Plus the
-bitset-NFA arm's word-level equality with the dense DFA, the Pallas
-kernel in interpret mode, and the autotuner's cache/record mechanics.
+the unfused `verdict_step` on every output lane, for both scan arms a
+field can be planned onto — the rule's plan and the explicit NFA plan
+(`megakernel.nfa_plan`) — over the golden 5000-flow corpus, curated
+edge policies (LOG header matches, dead secret-backed rules,
+multi-ruleset membership), and hypothesis-random rule banks/payloads.
+Plus the bitset-NFA arm's word-level equality with the dense DFA, the
+Pallas kernel in interpret mode, and the rule's per-field plan.
 """
 
 import numpy as np
@@ -50,6 +51,29 @@ def _engine(per_identity, cfg):
     return Loader(cfg).regenerate(per_identity, revision=1), cfg
 
 
+def _nfa_engine(per_identity, cfg):
+    """An engine restaged on the explicit NFA plan: every field whose
+    banks fit the arm's position budget scans through it."""
+    from cilium_tpu.engine import megakernel
+
+    engine, cfg = _engine(per_identity, cfg)
+    engine.stage_plan(megakernel.nfa_plan(engine.policy, cfg.engine))
+    return engine, cfg
+
+
+def _plan_on_tpu(engine, cfg, monkeypatch):
+    """The rule's plan for ``engine``'s policy as a TPU backend would
+    stage it. Only the planning sees the "tpu" reading: the step then
+    traces on the CPU, where the NFA arm runs in plain XLA."""
+    import jax
+
+    from cilium_tpu.engine import megakernel
+
+    with monkeypatch.context() as m:
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        return megakernel.plan_for_engine(engine.policy, cfg.engine)
+
+
 def _assert_fused_equals_legacy(engine, flows, cfg):
     """Engine's staged (fused) step vs the legacy verdict_step, all
     output lanes."""
@@ -79,14 +103,6 @@ def test_fused_bit_equal_per_config(config, n_rules):
         synth.scenario_by_name(config, n_rules, 512))
     engine, cfg = _engine(per_identity, _cfg())
     assert engine.impl_plan, "fused step should be staged by default"
-    _assert_fused_equals_legacy(engine, scenario.flows, cfg)
-
-
-def test_fused_legacy_knob_reverts_wholesale():
-    per_identity, scenario = synth.realize_scenario(
-        synth.synth_http_scenario(n_rules=40, n_flows=64))
-    engine, cfg = _engine(per_identity, _cfg(kernel_impl="legacy"))
-    assert engine.impl_plan == {}
     _assert_fused_equals_legacy(engine, scenario.flows, cfg)
 
 
@@ -265,94 +281,86 @@ def test_pallas_nfa_interpret_equals_dense():
                for k, v in nfa_kernel.stack_nfa_banks(banks).items()}
     got = np.asarray(jax.jit(
         lambda s, d, l: nfa_kernel.nfa_scan_banked(
-            s, d, l, use_pallas=True, interpret=True))(
+            s, d, l, interpret=True))(
         stacked, jnp.asarray(data), jnp.asarray(lens)))
     np.testing.assert_array_equal(want, got)
 
 
 def test_forced_nfa_arm_full_engine_bit_equal():
-    """kernel_impl=nfa-bitset forces the arm engine-wide (bank_size
-    small enough that every bank fits the position budget) — the full
+    """The explicit NFA plan puts the arm engine-wide (bank_size small
+    enough that every bank fits the position budget) — the full
     verdict must still be bit-equal."""
     per_identity, scenario = synth.realize_scenario(
         synth.synth_http_scenario(n_rules=24, n_flows=256))
-    engine, cfg = _engine(per_identity,
-                          _cfg(kernel_impl="nfa-bitset", bank_size=4))
+    engine, cfg = _nfa_engine(per_identity, _cfg(bank_size=4))
     assert "nfa-bitset" in engine.impl_plan.values(), engine.kernel_report
+    assert engine.policy.kernel_plan == engine.impl_plan
     _assert_fused_equals_legacy(engine, scenario.flows, cfg)
 
 
-def test_forced_nfa_ineligible_falls_back_dense():
-    """A bank over the position budget degrades the forced arm to
-    dense for that field — recorded on the plan, verdicts unchanged."""
+def test_forced_nfa_ineligible_falls_back_dense(monkeypatch):
+    """An ineligible bank plans dense: the 200-rule path banks pass 128
+    DFA states, but their positions bust the NFA arm's budget, so the
+    rule keeps them dense even on a TPU, and the explicit NFA plan
+    cannot take them either — verdicts unchanged."""
     per_identity, scenario = synth.realize_scenario(
         synth.synth_http_scenario(n_rules=200, n_flows=64))
-    engine, cfg = _engine(per_identity, _cfg(kernel_impl="nfa-bitset"))
+    engine, cfg = _engine(per_identity, _cfg())
+    plan, extra, report = _plan_on_tpu(engine, cfg, monkeypatch)
+    assert report["path"]["dfa_states"] > 128
+    assert report["path"]["nfa_positions"] is None
+    assert plan["path"] == "dfa-dense"
+    assert not any(k.startswith("path_") for k in extra)
+    engine, cfg = _nfa_engine(per_identity, cfg)
     assert engine.impl_plan["path"] == "dfa-dense"
     _assert_fused_equals_legacy(engine, scenario.flows, cfg)
 
 
-# --------------------------------------------------------------- autotune
-def test_autotune_mechanics_and_recording():
-    import jax
-
-    from cilium_tpu.core.config import EngineConfig
-    from cilium_tpu.engine import megakernel, nfa_kernel
-    from cilium_tpu.policy.compiler.dfa import compile_patterns
-    from cilium_tpu.runtime.metrics import (
-        KERNEL_AUTOTUNE_PICKS,
-        METRICS,
-    )
-
-    pats = [f"/t{i}/x" for i in range(6)]
-    banked = compile_patterns(pats, bank_size=3)
-    st = banked.stacked()
-    arrays = {f"at_{k}": jax.device_put(v) for k, v in st.items()}
-    banks = nfa_kernel.banks_from_dfa(banked, EngineConfig())
-    stacked = nfa_kernel.stack_nfa_banks(banks)
-    megakernel._AUTOTUNE_CACHE.clear()
-    r1 = megakernel.autotune_field("at-test", arrays, "at", stacked,
-                                   width=16, interpret=True,
-                                   probe_batch=64)
-    assert r1["impl"] in ("dfa-dense", "nfa-bitset")
-    assert r1["dense_ms"] is not None and r1["nfa_ms"] is not None
-    picks = METRICS.get(KERNEL_AUTOTUNE_PICKS,
-                        {"impl": r1["impl"], "field": "at-test"})
-    assert picks >= 1
-    # shape-key cache: second call re-serves without re-benching
-    r2 = megakernel.autotune_field("at-test", arrays, "at", stacked,
-                                   width=16, interpret=True,
-                                   probe_batch=64)
-    assert r2 is r1
-    # snapshot → adopt round-trips into a cold cache
-    snap = megakernel.autotune_cache_snapshot()
-    megakernel._AUTOTUNE_CACHE.clear()
-    megakernel.autotune_cache_adopt(snap)
-    r3 = megakernel.autotune_field("at-test", arrays, "at", stacked,
-                                   width=16, interpret=True,
-                                   probe_batch=64)
-    assert r3["impl"] == r1["impl"]
+#: a path the DFA needs 2^8 states for (it remembers which of the last
+#: eight bytes was an "x") and the NFA 12 positions: the NFA arm's regime
+WIDE_PATH = "/a/.*x[a-z][a-z][a-z][a-z][a-z][a-z][a-z]"
 
 
-def test_autotune_mode_stages_and_records_plan():
-    per_identity, scenario = synth.realize_scenario(
-        synth.synth_http_scenario(n_rules=16, n_flows=64))
-    engine, cfg = _engine(per_identity,
-                          _cfg(kernel_impl="autotune", bank_size=4))
-    # every field carries a measured or eligible-arm report
-    assert set(engine.kernel_report) == {"path", "method", "host",
-                                         "hdr", "dns"}
-    for rep in engine.kernel_report.values():
-        assert rep["impl"] in ("dfa-dense", "nfa-bitset")
-        assert rep["dense_ms"] is not None
-    # picks ride the policy and the loader's registry/status
-    assert engine.policy.kernel_plan == engine.impl_plan
-    loader = Loader(_cfg())
-    loader.regenerate(per_identity, revision=2)
-    status = loader.bank_status()
-    assert status["enabled"]
-    assert "kernel_plan" in status
-    _assert_fused_equals_legacy(engine, scenario.flows, cfg)
+def _wide_path_policy():
+    per_identity, scenario = _http_policy([
+        PortRuleHTTP(path=WIDE_PATH, method="GET"),
+        PortRuleHTTP(path="/b/[0-9]+", method="POST")])
+    flows = _flows(scenario.ids, ["/a/zzxabcdefg", "/a/xabcdef",
+                                  "/a/qqxqqqqqqqq", "/b/42", "/b/x"])
+    return per_identity, flows
+
+
+def test_rule_plans_nfa_for_a_wide_eligible_stack_on_tpu(monkeypatch):
+    """On a TPU the rule puts a stack past 128 DFA states whose NFA fits
+    on the NFA arm, and keeps the small stacks dense; off it every
+    stack stays dense. The TPU plan's verdicts are bit-equal."""
+    per_identity, flows = _wide_path_policy()
+    engine, cfg = _engine(per_identity, _cfg())
+    assert set(engine.impl_plan.values()) == {"dfa-dense"}
+    plan = _plan_on_tpu(engine, cfg, monkeypatch)
+    impl_plan, extra, report = plan
+    assert report["path"]["dfa_states"] > 128
+    assert impl_plan["path"] == "nfa-bitset"
+    assert "path_nfa_follow" in extra
+    assert {impl_plan[f] for f in ("method", "host", "hdr", "dns")} \
+        == {"dfa-dense"}
+    engine.stage_plan(plan)
+    _assert_fused_equals_legacy(engine, flows, cfg)
+
+
+def test_quarantined_build_plans_dense(monkeypatch):
+    """A build serving quarantined banks (stale covers the NFA cannot
+    rebuild) keeps every stack dense, even where the rule would give
+    the NFA arm the stack on a TPU."""
+    import dataclasses
+
+    per_identity, _ = _wide_path_policy()
+    engine, cfg = _engine(per_identity, _cfg())
+    engine.policy = dataclasses.replace(engine.policy,
+                                        bank_quarantined=("stale",))
+    impl_plan, extra, _ = _plan_on_tpu(engine, cfg, monkeypatch)
+    assert set(impl_plan.values()) == {"dfa-dense"}
+    assert not extra
 
 
 # ------------------------------------------------ golden corpus (at size)
@@ -360,7 +368,7 @@ def test_autotune_mode_stages_and_records_plan():
 def test_golden_5000_flow_fused_bit_equal_both_arms():
     """The acceptance differential at size: 5000 flows over a policy
     whose banks fit both arms; the fused step must be bit-equal to
-    the legacy path with the scan forced through EACH autotuner arm,
+    the unfused step on the rule's plan and on the explicit NFA plan,
     and through capture replay (the staged-table + group-word path)."""
     import itertools
 
@@ -368,11 +376,10 @@ def test_golden_5000_flow_fused_bit_equal_both_arms():
     from cilium_tpu.ingest import binary
 
     scen = synth.synth_http_scenario(n_rules=48, n_flows=5000)
-    for impl in ("dfa-dense", "nfa-bitset"):
+    for stage in (_engine, _nfa_engine):
         per_identity, scenario = synth.realize_scenario(scen)
-        engine, cfg = _engine(per_identity,
-                              _cfg(kernel_impl=impl, bank_size=4))
-        if impl == "nfa-bitset":
+        engine, cfg = stage(per_identity, _cfg(bank_size=4))
+        if stage is _nfa_engine:
             assert "nfa-bitset" in engine.impl_plan.values()
         _assert_fused_equals_legacy(engine, scenario.flows, cfg)
 
@@ -431,13 +438,13 @@ if given is not None:
     @settings(max_examples=25, deadline=None)
     def test_hypothesis_fused_equals_legacy(rules, payloads, n_sel,
                                             data):
-        """Random rule banks + random payloads: the fused step (both
-        arms where eligible) is bit-equal to the legacy step."""
+        """Random rule banks + random payloads: the fused step (the
+        rule's plan, or the NFA plan where eligible) is bit-equal to
+        the unfused step."""
         per_identity, scenario = _http_policy(rules,
                                               n_selectors=n_sel)
-        impl = data.draw(st.sampled_from(["auto", "nfa-bitset"]))
-        engine, cfg = _engine(per_identity,
-                              _cfg(kernel_impl=impl, bank_size=4))
+        stage = data.draw(st.sampled_from([_engine, _nfa_engine]))
+        engine, cfg = stage(per_identity, _cfg(bank_size=4))
         flows = _flows(scenario.ids,
                        ["/" + p if not p.startswith("/") else p
                         for p in payloads],

@@ -1,10 +1,10 @@
 """Pallas DFA kernel ≡ XLA gather scan (interpret mode on CPU, asked
-for explicitly: the engine never infers it from the backend).
+for explicitly: off the TPU the rule never picks the kernel).
 
 The kernel's contract (engine/pallas_dfa.py): identical final states /
 accept words to the gather path for any bank of at most
 ``MAX_STATES`` padded states; state ids past 64 and past 256 exercise
-both hi/lo planes. Plus the default pick's shape rule
+both hi/lo planes. Plus the one rule that picks every scan arm
 (``dfa_kernel.pick_arm``).
 """
 
@@ -110,8 +110,8 @@ def test_pallas_rejects_oversized_bank():
 
 
 def test_pallas_refuses_large_banks_in_banked_entry():
-    # the banked entry raises past MAX_STATES: an explicit pallas pick
-    # is never silently swapped for the data-dependent gather
+    # the banked entry raises past MAX_STATES: a named pallas arm is
+    # never silently swapped for the data-dependent gather
     over = pallas_dfa.MAX_STATES + 1
     trans = np.zeros((2, over, 2), np.int32)
     with pytest.raises(ValueError, match="pallas"):
@@ -123,30 +123,62 @@ def test_pallas_refuses_large_banks_in_banked_entry():
                         impl="pallas", interpret=True)
 
 
-@pytest.mark.parametrize("backend,states,classes,rows,arm", [
-    ("cpu", 2048, 28, 8192, "gather"),    # off the TPU: always gather
-    ("gpu", 2048, 28, 8192, "gather"),
-    ("tpu", 2048, 28, 8192, "pallas"),    # the 1k-rule path piece
-    ("tpu", 129, 28, 1024, "pallas"),     # one tile, just past 128
-    ("tpu", 128, 28, 8192, "gather"),     # ≤128 states keep the gather
-    ("tpu", 78, 20, 8192, "gather"),
-    ("tpu", 2048, 28, 256, "gather"),     # a sub-tile batch
-    ("tpu", 2048, 28, 1023, "gather"),
-    ("tpu", 8192, 28, 8192, "pallas"),    # the state ceiling
-    ("tpu", 8193, 28, 8192, "gather"),    # past it
-    ("tpu", 2048, 65, 8192, "gather"),    # past one MXU pass of planes
-])
-def test_pick_arm_by_bank_shape(backend, states, classes, rows, arm):
-    assert pick_arm(backend, states, classes, rows) == arm
+#: (backend, states, classes, rows, nfa, arm): ``nfa`` None asks the
+#: rule as the trace does, for a dense stack; True/False is whether the
+#: stack's NFA tensors could be built, as staging asks it (False: a
+#: bank past the position budget, or a quarantined build)
+PICKS = [
+    ("cpu", 2048, 28, 8192, None, "gather"),  # off the TPU: always gather
+    ("gpu", 2048, 28, 8192, None, "gather"),
+    ("tpu", 2048, 28, 8192, None, "pallas"),  # the 1k-rule path piece
+    ("tpu", 129, 28, 1024, None, "pallas"),   # one tile, just past 128
+    ("tpu", 128, 28, 8192, None, "gather"),   # ≤128 states keep the gather
+    ("tpu", 78, 20, 8192, None, "gather"),
+    ("tpu", 2048, 28, 256, None, "gather"),   # a sub-tile batch
+    ("tpu", 2048, 28, 1023, None, "gather"),
+    ("tpu", 8192, 28, 8192, None, "pallas"),  # the state ceiling
+    ("tpu", 8193, 28, 8192, None, "gather"),  # past it
+    ("tpu", 2048, 65, 8192, None, "gather"),  # past one MXU pass of planes
+    ("tpu", 2048, 28, 8192, True, "nfa"),     # past 128 states, NFA fits
+    ("tpu", 129, 6, 0, True, "nfa"),          # staging asks with no rows
+    ("tpu", 2048, 28, 8192, False, "pallas"),  # NFA ineligible: dense
+    ("tpu", 2048, 28, 256, False, "gather"),
+    ("tpu", 128, 28, 8192, True, "gather"),   # ≤128 states: NFA not asked
+    ("cpu", 2048, 28, 8192, True, "gather"),  # off the TPU: not asked
+    ("gpu", 512, 6, 0, True, "gather"),
+]
 
 
-def test_scan_arm_keeps_explicit_picks():
+@pytest.mark.parametrize(
+    "backend,states,classes,rows,nfa,arm", PICKS,
+    ids=["-".join(str(v) for v in p if v is not None) for p in PICKS])
+def test_pick_arm_by_bank_shape(backend, states, classes, rows, nfa,
+                                arm):
+    if nfa is None:
+        assert pick_arm(backend, states, classes, rows) == arm
+        return
+    asked = []
+
+    def nfa_fits():
+        asked.append(1)
+        return nfa
+
+    assert pick_arm(backend, states, classes, rows, nfa_fits) == arm
+    # the NFA build costs host time: it is asked for only in the arm's
+    # regime, the TPU past 128 padded states
+    assert bool(asked) == (backend == "tpu" and states > 128)
+
+
+def test_scan_arm_asks_the_rule_on_the_default_backend(monkeypatch):
+    import jax
+
     shape = (7, 2048, 28)
-    for impl in ("gather", "onehot", "pallas"):
-        assert scan_arm(impl, shape, 8192) == impl
-    # the default pick on this (CPU) backend: the gather
-    assert scan_arm(None, shape, 8192) == "gather"
-    assert scan_arm("auto", shape, 8192) == "gather"
+    assert scan_arm(None, shape, 8192) == "gather"   # the CPU here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert scan_arm(None, shape, 8192) == "pallas"
+    assert scan_arm(None, shape, 256) == "gather"
+    # a test may still name the arm
+    assert scan_arm("gather", shape, 8192) == "gather"
 
 
 def test_auto_pick_runs_the_kernel_on_a_tpu_backend(monkeypatch):

@@ -311,6 +311,7 @@ def test_engine_probe_attributes_fused_step_as_one_dispatch(
 def test_engine_probe_nfa_impl_phase_label():
     """A plan that uses the bitset-NFA arm reports its phase lane."""
     from cilium_tpu.core.config import Config
+    from cilium_tpu.engine.megakernel import nfa_plan
     from cilium_tpu.engine.phases import EnginePhaseProbe
     from cilium_tpu.runtime.loader import Loader
 
@@ -318,9 +319,9 @@ def test_engine_probe_nfa_impl_phase_label():
         synth.synth_http_scenario(n_rules=12, n_flows=64))
     cfg = Config.from_env()
     cfg.enable_tpu_offload = True
-    cfg.engine.kernel_impl = "nfa-bitset"
     cfg.engine.bank_size = 4
     engine = Loader(cfg).regenerate(per_identity, revision=1)
+    engine.stage_plan(nfa_plan(engine.policy, cfg.engine))
     assert "nfa-bitset" in engine.impl_plan.values()
     report = EnginePhaseProbe(engine).measure_flows(
         scenario.flows[:64], cfg.engine, reps=2)

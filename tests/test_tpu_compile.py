@@ -12,7 +12,7 @@ it, or the 4-device mesh — and hold each program's
 * ``dfa_finals_pallas`` at its state ceiling, and at the 1k-rule path
   stack's own bank, state and class counts (widths 32 and 256);
 * ``fused_verdict_step`` at the 1k-rule http policy's array shapes
-  with the impl plan the TPU ``auto`` plan picks;
+  with the impl plan the rule picks on a TPU;
 * the DP lane step over a 4-device mesh.
 
 The topology is described inside a module fixture (never at import):
@@ -67,8 +67,8 @@ def one_chip(topo, no_compile_cache):
 @pytest.fixture(scope="module")
 def http_1k(tmp_path_factory):
     """The 1k-rule http policy (BASELINE configs[1]) as the Loader
-    stages it, plus the NFA tensors and impl plan the TPU ``auto``
-    plan picks for it (``plan_for_engine`` reads the backend, so the
+    stages it, plus the NFA tensors and impl plan the rule picks for
+    it on a TPU (``plan_for_engine`` reads the backend, so the
     test steers it to "tpu" here)."""
     import jax
 
@@ -114,7 +114,7 @@ def _fits(compiled) -> int:
 
 def test_tpu_auto_plan_at_1k_rules(http_1k):
     """The plan the fused-step compile below uses. The 1k-rule path
-    banks blow the NFA arm's 128-position tile, so ``auto`` on TPU
+    banks blow the NFA arm's 128-position tile, so the rule on a TPU
     keeps every field on the dense arm at this policy (within it,
     ``dfa_kernel.pick_arm`` sends the path banks to the MXU kernel
     over batches of a full tile)."""
@@ -209,8 +209,7 @@ def test_fused_step_compiles_at_1k_rule_shapes(http_1k, one_chip):
 
     step = jax.jit(functools.partial(
         fused_verdict_step,
-        impl_plan=tuple(sorted(http_1k["plan"].items())),
-        dfa_impl="gather", use_pallas_nfa=True))
+        impl_plan=tuple(sorted(http_1k["plan"].items()))))
     compiled = step.lower(_sds(http_1k["arrays"], one_chip),
                           _sds(_host_batch(http_1k), one_chip)).compile()
     _fits(compiled)
