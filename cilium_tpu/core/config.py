@@ -62,7 +62,8 @@ class EngineConfig:
     #: the staged unique-row table is discarded (the id stream would
     #: move MORE bytes than plain rows, and the table ≈ a full copy of
     #: the capture in host memory) and replay streams full rows.
-    #: 1.0 = always keep the table; see CaptureReplay.stage_unique.
+    #: 1.0 = keep the table unless every row is distinct; see
+    #: CaptureReplay.stage_unique.
     stage_unique_drop_ratio: float = 0.5
     #: device-resident verdict memo over the deduped replay rows
     #: (engine/memo.py): unique rows are verdicted once per policy

@@ -2031,6 +2031,7 @@ import time as _time
 
 from cilium_tpu.runtime import faults as _faults
 from cilium_tpu.runtime.metrics import (
+    CAPTURE_DEDUP_DECISIONS as _CAPTURE_DEDUP_DECISIONS,
     CAPTURE_STAGE_SECONDS as _CAPTURE_STAGE_SECONDS,
     CAPTURE_TABLE_PIECES as _CAPTURE_TABLE_PIECES,
     METRICS as _METRICS,
@@ -2487,11 +2488,42 @@ class CaptureReplay:
         the id stream a net loss AND the unique table ≈ a full copy of
         ``rows_all`` — past this ratio the table/ids are discarded
         immediately (``row_idx`` stays None) instead of pinning ~2× the
-        capture in host memory for a session that will stream rows."""
+        capture in host memory for a session that will stream rows.
+
+        Where one string-row column holds a distinct value in every
+        staged row, every row is distinct: the ratio is exactly 1.0 and
+        the table is dropped before anything is hashed or built
+        (``strings``). Otherwise the table is built and the exact ratio
+        decides (``rows``). The decision, ``n_unique`` and the returned
+        ratio are the same either way; the one that decided is counted
+        in ``cilium_tpu_capture_dedup_decisions_total{by, outcome}``."""
         assert self.rows_all is not None, "stage_rows first"
         self._drop_ratio = drop_if_ratio_at_least
         with _StagePhase("dedup"):
             return self._stage_unique(drop_if_ratio_at_least)
+
+    def _string_column_distinct(self) -> bool:
+        """Whether one string-row column of the staged block holds a
+        distinct value in every row, counted over the staged rows. A
+        column indexes its padded table, so one whose table has fewer
+        rows than the block repeats and is not counted."""
+        n = len(self.rows_all)
+        tables = self.feat.padded_rows()
+        for field, _ in CaptureFeaturizer._FIELD_CAPS:
+            col = self.rows_all[:, _ROW_COLS.index(f"{field}_row")]
+            if tables[field] >= n \
+                    and np.count_nonzero(np.bincount(col)) == n:
+                return True
+        return False
+
+    def _drop_dedup(self, by: str, n_true: int) -> float:
+        self._uniq_host = None
+        self.unique_rows = None
+        self.row_idx = None
+        self.n_unique = n_true
+        _METRICS.inc(_CAPTURE_DEDUP_DECISIONS,
+                     labels={"by": by, "outcome": "dropped"})
+        return n_true / max(1, len(self.rows_all))
 
     def _stage_unique(self, drop_if_ratio_at_least: Optional[float]
                       = None) -> float:
@@ -2503,6 +2535,10 @@ class CaptureReplay:
         # hash-assigned — the key the verdict memo rides.
         from cilium_tpu.engine.memo import hash_rows
 
+        drop = drop_if_ratio_at_least
+        if drop is not None and len(self.rows_all) and drop <= 1.0 \
+                and self._string_column_distinct():
+            return self._drop_dedup("strings", len(self.rows_all))
         h = hash_rows(self.rows_all)
         _, first, inverse = np.unique(h, return_index=True,
                                       return_inverse=True)
@@ -2512,13 +2548,10 @@ class CaptureReplay:
                                       return_inverse=True)
         n_true = len(uniq)
         ratio = n_true / max(1, len(self.rows_all))
-        if drop_if_ratio_at_least is not None \
-                and ratio >= drop_if_ratio_at_least:
-            self._uniq_host = None
-            self.unique_rows = None
-            self.row_idx = None
-            self.n_unique = n_true
-            return ratio
+        if drop is not None and ratio >= drop:
+            return self._drop_dedup("rows", n_true)
+        _METRICS.inc(_CAPTURE_DEDUP_DECISIONS,
+                     labels={"by": "rows", "outcome": "kept"})
         uniq = _pad_rows_pow2(uniq)
         self._uniq_host = uniq
         self.unique_rows = None

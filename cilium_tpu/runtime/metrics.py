@@ -92,6 +92,10 @@ CAPTURE_STAGE_SECONDS = "cilium_tpu_capture_stage_seconds"
 #: string-table row pieces dispatched to the device scan, by field
 #: (engine/verdict.stage_capture_tables: one per piece of each table)
 CAPTURE_TABLE_PIECES = "cilium_tpu_capture_table_pieces_total"
+#: capture-replay dedup decisions, by what decided (strings: one
+#: string-row column tells every row apart; rows: the built table) and
+#: outcome (dropped / kept) — CaptureReplay.stage_unique
+CAPTURE_DEDUP_DECISIONS = "cilium_tpu_capture_dedup_decisions_total"
 #: collective ops recorded by the trace-time ledger, by site/op/axis
 #: (counts are per compiled block execution — see parallel/collectives)
 COLLECTIVE_OPS = "cilium_tpu_collective_ops_total"
@@ -730,6 +734,10 @@ METRICS.describe(CAPTURE_STAGE_SECONDS,
 METRICS.describe(CAPTURE_TABLE_PIECES,
                  "capture string-table row pieces dispatched to the "
                  "device scan, by field")
+METRICS.describe(CAPTURE_DEDUP_DECISIONS,
+                 "capture-replay dedup decisions, by what decided "
+                 "(a string-row column or the built table) and the "
+                 "outcome")
 METRICS.describe(COLLECTIVE_OPS,
                  "collective ops recorded at trace time, by "
                  "site/op/axis (count per compiled block)")

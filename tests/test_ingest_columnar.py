@@ -4,7 +4,9 @@ Differential discipline: the columnar encoders are pinned to the
 per-record reference encoders (``binary.flows_to_capture_l7`` /
 the Flow-object JSONL path) field by field, the streaming record-batch
 writer (native AND numpy fallback) is pinned byte-for-byte, the
-hash-keyed dedup is pinned to the exact row sort, and the memo-backed
+hash-keyed dedup is pinned to the exact row sort (its drop, decided
+early where one string column tells every row apart, to the
+build-then-decide dedup), and the memo-backed
 replay is pinned bit-for-bit to ``verdict_flows`` — including across
 policy-generation invalidations and auth-view changes.
 """
@@ -220,6 +222,204 @@ def test_hash_collision_falls_back_to_exact(monkeypatch):
     assert replay.n_unique == len(np.unique(rows, axis=0))
     np.testing.assert_array_equal(
         replay._uniq_host[replay.row_idx], rows)
+
+
+def _build_then_decide(rows, drop):
+    """The reference dedup: build the whole hash-keyed table, then
+    look at the ratio. Returns
+    ``(ratio, n_unique, unique table or None, row ids or None)``."""
+    from cilium_tpu.engine.memo import hash_rows
+    from cilium_tpu.engine.verdict import _pad_rows_pow2
+
+    h = hash_rows(rows)
+    _, first, inverse = np.unique(h, return_index=True,
+                                  return_inverse=True)
+    uniq = rows[first]
+    if not np.array_equal(uniq[inverse], rows):
+        uniq, inverse = np.unique(rows, axis=0, return_inverse=True)
+    n_true = len(uniq)
+    ratio = n_true / max(1, len(rows))
+    if drop is not None and ratio >= drop:
+        return ratio, n_true, None, None
+    uniq = _pad_rows_pow2(uniq)
+    idx_dtype = np.uint16 if len(uniq) <= (1 << 16) else np.int32
+    return ratio, n_true, uniq, inverse.astype(idx_dtype)
+
+
+def _dedup_flows(n, k, vary, n_paths=4):
+    """``n`` HTTP flows cycling over ``k`` distinct rows. ``vary`` says
+    what tells the distinct rows apart: ``paths`` gives each its own
+    path (the path column counts ``k`` values), ``ports`` its own port
+    over ``n_paths`` shared paths (the path column counts ``n_paths``)."""
+    from cilium_tpu.core.flow import HTTPInfo, L7Type
+
+    def row(i):
+        path = f"/r{i}" if vary == "paths" else f"/s{i % n_paths}"
+        port = 80 if vary == "paths" else 1000 + i
+        return Flow(src_identity=1, dst_identity=2, dport=port,
+                    protocol=Protocol.TCP,
+                    direction=TrafficDirection.INGRESS, l7=L7Type.HTTP,
+                    http=HTTPInfo(method="GET", path=path, host="h"))
+
+    return [row(i % k) for i in range(n)]
+
+
+def _decisions():
+    from cilium_tpu.runtime.metrics import (
+        CAPTURE_DEDUP_DECISIONS,
+        METRICS,
+    )
+
+    return {(by, out): METRICS.get(CAPTURE_DEDUP_DECISIONS,
+                                   {"by": by, "outcome": out})
+            for by in ("strings", "rows")
+            for out in ("dropped", "kept")}
+
+
+def _staged(engine, cfg, flows, lo=0, hi=None):
+    from cilium_tpu.engine.verdict import CaptureReplay
+
+    cols = flows_to_columns(flows)
+    replay = CaptureReplay(engine, cols.l7, cols.offsets, cols.blob,
+                           cfg.engine, gen=cols.gen)
+    replay.stage_rows(cols.rec[lo:hi], cols.l7[lo:hi])
+    return replay
+
+
+def _stage_counted(replay, drop):
+    """``stage_unique(drop)`` → (ratio, the one decision it counted)."""
+    before = _decisions()
+    ratio = replay.stage_unique(drop_if_ratio_at_least=drop)
+    moved = [k for k, v in _decisions().items() if v != before[k]]
+    assert len(moved) == 1
+    assert _decisions()[moved[0]] == before[moved[0]] + 1
+    return ratio, moved[0]
+
+
+def _assert_matches_build_first(replay, drop, ratio):
+    want_ratio, want_n, want_uniq, want_idx = _build_then_decide(
+        replay.rows_all, drop)
+    assert ratio == want_ratio
+    assert replay.n_unique == want_n
+    if want_uniq is None:
+        assert replay.row_idx is None and replay._uniq_host is None
+    else:
+        assert replay._uniq_host.dtype == want_uniq.dtype
+        np.testing.assert_array_equal(replay._uniq_host, want_uniq)
+        assert replay.row_idx.dtype == want_idx.dtype
+        np.testing.assert_array_equal(replay.row_idx, want_idx)
+
+
+def test_all_distinct_paths_drop_without_hashing(monkeypatch):
+    """Every path distinct: the path column alone tells every row
+    apart, so the table is dropped and no row is hashed."""
+    import cilium_tpu.engine.memo as memo_mod
+
+    cfg, engine, _ = _engine_for("http", n_rules=6, n_flows=80)
+    replay = _staged(engine, cfg, _dedup_flows(96, 96, "paths"))
+
+    def no_hash(rows):
+        raise AssertionError("hash_rows called")
+
+    monkeypatch.setattr(memo_mod, "hash_rows", no_hash)
+    ratio, decided = _stage_counted(replay, 0.5)
+    assert ratio == 1.0
+    assert replay.n_unique == 96
+    assert replay.row_idx is None and replay._uniq_host is None
+    assert decided == ("strings", "dropped")
+
+
+@pytest.mark.parametrize("vary", ["paths", "ports"])
+@pytest.mark.parametrize("k", [30, 49, 50, 51, 90, 100])
+def test_bounded_drop_matches_exact_unique(k, vary):
+    """At distinct-row shares either side of the 0.5 drop ratio, the
+    ratio, ``n_unique`` and the drop equal the exact row unique and
+    the build-then-decide dedup, bit for bit where the table is kept.
+    ``ports`` keeps every string column's distinct count under the
+    row count, so the exact count decides; ``paths`` at 1.0 gives every
+    row its own path, so that column alone decides."""
+    cfg, engine, _ = _engine_for("http", n_rules=6, n_flows=80)
+    replay = _staged(engine, cfg, _dedup_flows(100, k, vary))
+    ratio, decided = _stage_counted(replay, 0.5)
+    n_exact = len(np.unique(replay.rows_all, axis=0))
+    assert n_exact == k
+    assert replay.n_unique == k and ratio == k / 100
+    assert (replay.row_idx is None) == (k / 100 >= 0.5)
+    _assert_matches_build_first(replay, 0.5, ratio)
+    if k / 100 < 0.5:
+        want = ("rows", "kept")
+    else:
+        want = ("strings" if vary == "paths" and k == 100 else "rows",
+                "dropped")
+    assert decided == want
+
+
+@pytest.mark.parametrize("vary,k,collide,by", [
+    ("paths", 90, "total", "rows"),
+    ("ports", 90, "total", "rows"),
+    ("ports", 90, "pair", "rows"),
+    ("paths", 100, "total", "strings"),
+])
+def test_bounded_drop_exact_under_hash_collision(monkeypatch, vary, k,
+                                                 collide, by):
+    """Planted hash collisions in the drop regime: whichever check
+    decides, ``n_unique`` stays the exact distinct-row count."""
+    import cilium_tpu.engine.memo as memo_mod
+
+    real = memo_mod.hash_rows
+    def pair(rows):  # the rows of the largest hash take the least
+        h = real(rows)
+        return np.where(h == h.max(), h.min(), h)
+
+    planted = {"total": lambda r: np.zeros(len(r), dtype=np.uint64),
+               "pair": pair}[collide]
+    monkeypatch.setattr(memo_mod, "hash_rows", planted)
+    cfg, engine, _ = _engine_for("http", n_rules=6, n_flows=80)
+    replay = _staged(engine, cfg, _dedup_flows(100, k, vary))
+    d = len(np.unique(planted(replay.rows_all)))
+    assert d < k  # the planted hash does merge distinct rows
+    ratio, decided = _stage_counted(replay, 0.5)
+    assert replay.n_unique == k and ratio == k / 100
+    assert replay.row_idx is None
+    assert decided == (by, "dropped")
+
+
+@pytest.mark.parametrize("drop", [None, 0.5, 0.9, 1.0])
+def test_kept_dedup_bit_identical_to_build_first(drop):
+    """Where the table is kept, its rows, the per-flow ids and their
+    dtypes are the build-then-decide dedup's, bit for bit: the memo
+    rides these ids."""
+    cfg, engine, scenario = _engine_for("http", n_rules=10,
+                                        n_flows=200)
+    replay = _staged(engine, cfg, scenario.flows)
+    ratio, decided = _stage_counted(replay, drop)
+    assert replay.row_idx is not None
+    assert decided == ("rows", "kept")
+    _assert_matches_build_first(replay, drop, ratio)
+
+
+@pytest.mark.parametrize("lo,hi", [(50, 100), (0, 50)])
+def test_bound_counts_the_staged_slice(lo, hi):
+    """``stage_rows`` of a slice: the string columns are counted over
+    the staged rows, not the capture's strings. The capture's first half has 50
+    distinct paths, its second half one path over 5 ports."""
+    from cilium_tpu.core.flow import HTTPInfo, L7Type
+
+    flows = _dedup_flows(50, 50, "paths") + [
+        Flow(src_identity=1, dst_identity=2, dport=1000 + i % 5,
+             protocol=Protocol.TCP, direction=TrafficDirection.INGRESS,
+             l7=L7Type.HTTP, http=HTTPInfo(method="GET", path="/a",
+                                           host="h"))
+        for i in range(50)]
+    cfg, engine, _ = _engine_for("http", n_rules=6, n_flows=80)
+    replay = _staged(engine, cfg, flows, lo, hi)
+    ratio, decided = _stage_counted(replay, 0.5)
+    _assert_matches_build_first(replay, 0.5, ratio)
+    if lo == 50:
+        assert replay.n_unique == 5 and decided == ("rows", "kept")
+    else:
+        assert replay.n_unique == 50
+        assert decided == ("strings", "dropped")
 
 
 # ---------------------------------------------------------------------------
