@@ -957,9 +957,9 @@ class DSTWorld:
         from cilium_tpu.runtime.serveloop import LeaseExpired, ShedError
 
         if self._fleet is None:
+            self.loader.config.serve.pack_interval_ms = 10.0
             replicas = [HostReplica(i, self.loader, capacity=4,
-                                    lease_ttl_s=10.0,
-                                    pack_interval_s=0.01)
+                                    lease_ttl_s=10.0)
                         for i in range(3)]
             self._fleet = FleetRouter(replicas,
                                       heartbeat_interval_s=0.5,

@@ -92,6 +92,7 @@ from cilium_tpu.runtime.metrics import (
     FLEET_HOST_OCCUPANCY,
     FLEET_JOURNAL_EVENTS,
     FLEET_REJOINS,
+    FLEET_ROUTED_CHUNKS,
     FLEET_SLO_BURN_RATE,
     FLEET_SPILLED_STREAMS,
     FLEET_TRACE_STITCHES,
@@ -201,24 +202,26 @@ class HostDead(RuntimeError):
 
 
 class HostReplica:
-    """One simulated fleet host: a stable identity
+    """One fleet host: a stable identity
     (``parallel/multihost.host_id``), its own ServeLoop (ring +
-    incremental session) and its own bounded ExplainStore. The store
-    OUTLIVES the loop across death/rejoin — a trace served before the
-    host died still resolves after its warm restore, which is what
-    keeps ``GET /v1/explain`` regression-pinned across a handoff."""
+    incremental session) built from its loader's ``Config.serve``, and
+    its own bounded ExplainStore. ``capacity`` and ``lease_ttl_s``,
+    when given, override the config's slot capacity and lease TTL. The
+    store OUTLIVES the loop across death/rejoin — a trace served
+    before the host died still resolves after its warm restore, which
+    is what keeps ``GET /v1/explain`` regression-pinned across a
+    handoff."""
 
-    def __init__(self, index: int, loader, capacity: int = 1024,
-                 lease_ttl_s: float = 300.0,
-                 pack_interval_s: float = 0.05,
-                 max_slot_pending: int = 8):
+    def __init__(self, index: int, loader,
+                 capacity: Optional[int] = None,
+                 lease_ttl_s: Optional[float] = None):
         self.index = int(index)
         self.name = host_id(index)
         self.loader = loader
-        self.capacity = int(capacity)
-        self.lease_ttl_s = float(lease_ttl_s)
-        self.pack_interval_s = float(pack_interval_s)
-        self.max_slot_pending = int(max_slot_pending)
+        #: the loop's sizes that override ``Config.serve``
+        self._sizes = {k: v for k, v in (("capacity", capacity),
+                                         ("lease_ttl_s", lease_ttl_s))
+                       if v is not None}
         #: per-replica explain store (persists across death/rejoin)
         self.explain = ExplainStore()
         self.alive = True
@@ -234,12 +237,9 @@ class HostReplica:
         self.loop = self._make_loop()
 
     def _make_loop(self) -> ServeLoop:
-        return ServeLoop(self.loader, capacity=self.capacity,
-                         lease_ttl_s=self.lease_ttl_s,
-                         pack_interval_s=self.pack_interval_s,
-                         max_slot_pending=self.max_slot_pending,
-                         explain_store=self.explain,
-                         host_id=self.name)
+        return ServeLoop.from_config(
+            self.loader, self.loader.config.serve,
+            explain_store=self.explain, host_id=self.name, **self._sizes)
 
     def guard(self, new_stream: bool = False) -> None:
         """The host's own fail-closed gate, checked before any lease
@@ -420,6 +420,22 @@ class FleetRouter:
         self._note_regrant(stream_id)
         return target.name, lease
 
+    def release(self, stream_id: str) -> None:
+        """The stream ended (its session released the lease through
+        the replica's loop): unpin its placement, unless a resumed
+        connection holds a live lease there again, and refresh that
+        host's occupancy digest from its loop. The served path runs
+        no heartbeat, so stream end is where the digest catches up."""
+        replica = self.replica_of(stream_id)
+        if replica is None:
+            return
+        live = replica.loop.lease_ids()
+        with self._lock:
+            if stream_id not in live \
+                    and self.placements.get(stream_id) == replica.name:
+                self.placements.pop(stream_id, None)
+            self._digest[replica.name] = len(live)
+
     def replica_of(self, stream_id: str) -> Optional[HostReplica]:
         with self._lock:
             name = self.placements.get(stream_id)
@@ -437,6 +453,13 @@ class FleetRouter:
             raise HostDead("", f"stream {stream_id} has no live "
                                f"placement")
         replica.guard(new_stream=False)
+        ticket = self._submit_traced(replica, stream_id, lease, sections)
+        METRICS.inc(FLEET_ROUTED_CHUNKS, labels={"host": replica.name})
+        return ticket
+
+    def _submit_traced(self, replica: HostReplica, stream_id: str,
+                       lease, sections):
+        """``replica.loop.submit`` under the stream's trace context."""
         ctx = TRACER.current()
         if ctx is not None:
             # a client-side trace is active: remember its id so the
@@ -826,8 +849,18 @@ class FleetRouter:
         degenerates to one stitch over the shared ring."""
         return TRACER.stitch(trace_id)
 
+    def start(self) -> "FleetRouter":
+        """The serving face: each replica's loop on its own pack
+        thread (``ServeLoop.start``, named by the host), so the
+        replicas pack, dispatch and resolve side by side."""
+        for r in self.replicas:
+            r.loop.start()
+        return self
+
     def step_all(self) -> int:
-        """One pack cycle on every live replica (the driven face)."""
+        """One pack cycle on every live replica, one after another:
+        the driven face of the virtual-clock ``FleetModel`` and the
+        DST runner. The served path never calls it (:meth:`start`)."""
         served = 0
         for r in self.replicas:
             if r.alive:
@@ -1011,6 +1044,10 @@ class FleetModel:
             cfg = Config()
             cfg.enable_tpu_offload = True
             cfg.loader.cache_dir = self._cache_dir
+            # the model's pack cadence, and the load model's per-slot
+            # bound, as each replica's loop reads them
+            cfg.serve.pack_interval_ms = self.pack_interval_s * 1e3
+            cfg.serve.max_slot_pending = 8
             loader = Loader(cfg,
                             device=devices[host_idx % len(devices)])
             loader.regenerate(per_identity, revision=1)
@@ -1029,8 +1066,7 @@ class FleetModel:
                      engine.verdict_flows(flows)["verdict"]]
             pool.append(_Chunk(sections, truth))
         replicas = [HostReplica(i, loaders[i], capacity=self.capacity,
-                                lease_ttl_s=self.lease_ttl_s,
-                                pack_interval_s=self.pack_interval_s)
+                                lease_ttl_s=self.lease_ttl_s)
                     for i in range(self.hosts)]
         router = FleetRouter(
             replicas, heartbeat_interval_s=self.heartbeat_interval_s,

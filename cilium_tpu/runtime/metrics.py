@@ -226,6 +226,9 @@ FLEET_SPILLED_STREAMS = "cilium_tpu_fleet_spilled_streams_total"
 #: gauge: leased-slot occupancy per host, by host — the occupancy
 #: digest the fleet-coherent shed/spill decision reads
 FLEET_HOST_OCCUPANCY = "cilium_tpu_fleet_host_occupancy"
+#: chunks the router handed to a replica's loop, by host
+#: (``FleetRouter.submit``)
+FLEET_ROUTED_CHUNKS = "cilium_tpu_fleet_routed_chunks_total"
 
 # -- fleet observability plane (runtime/fleetserve.py + hubble/
 # flowagg.py): cross-host trace stitching, continuous flow export,
@@ -840,6 +843,8 @@ METRICS.describe(FLEET_SPILLED_STREAMS,
                  "owner for headroom")
 METRICS.describe(FLEET_HOST_OCCUPANCY,
                  "leased-slot occupancy per fleet host, by host")
+METRICS.describe(FLEET_ROUTED_CHUNKS,
+                 "chunks the fleet router handed to a replica, by host")
 METRICS.describe(FLEET_SLO_BURN_RATE,
                  "fleet burn-rate roll-up, by slo, window, and view "
                  "(worst single host / fleet-weighted)")

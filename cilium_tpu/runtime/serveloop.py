@@ -320,19 +320,23 @@ class ServeLoop:
         self.pack_seconds = 0.0
 
     @classmethod
-    def from_config(cls, loader, cfg, gate=None,
-                    authed_pairs_fn=None) -> "ServeLoop":
+    def from_config(cls, loader, cfg, **overrides) -> "ServeLoop":
         """Build from ``Config.serve`` (tolerates absence so embedders
-        with older configs keep working). Provenance and SLO knobs
-        come off the loader's ROOT config (``[provenance]``/``[slo]``)
-        inside ``__init__``."""
-        return cls(
-            loader,
-            capacity=getattr(cfg, "slot_capacity", 1024),
-            lease_ttl_s=getattr(cfg, "lease_ttl_s", 30.0),
-            pack_interval_s=getattr(cfg, "pack_interval_ms", 2.0) / 1e3,
-            max_slot_pending=getattr(cfg, "max_slot_pending", 64),
-            gate=gate, authed_pairs_fn=authed_pairs_fn)
+        with older configs keep working). ``overrides`` are constructor
+        keywords that win over the config: a service passes its
+        ``gate``/``authed_pairs_fn``, a fleet replica its ``host_id``,
+        ``explain_store`` and sizes. Provenance and SLO knobs come off
+        the loader's ROOT config (``[provenance]``/``[slo]``) inside
+        ``__init__``."""
+        kw = {
+            "capacity": getattr(cfg, "slot_capacity", 1024),
+            "lease_ttl_s": getattr(cfg, "lease_ttl_s", 30.0),
+            "pack_interval_s": getattr(cfg, "pack_interval_ms",
+                                       2.0) / 1e3,
+            "max_slot_pending": getattr(cfg, "max_slot_pending", 64),
+        }
+        kw.update(overrides)
+        return cls(loader, **kw)
 
     # -- leases -----------------------------------------------------------
     def _shed(self, reason: str, tenant: str = "") -> None:
@@ -723,11 +727,13 @@ class ServeLoop:
 
     def start(self) -> "ServeLoop":
         """Spawn the production pack thread (virtual-time ready: the
-        interval is a ``simclock.sleep``)."""
+        interval is a ``simclock.sleep``), named by the loop's host
+        when it serves as a fleet replica."""
         if self._thread is None:
+            name = "serve-pack-loop" + (f"-{self.host_id}"
+                                        if self.host_id else "")
             self._thread = threading.Thread(target=self._run,
-                                            daemon=True,
-                                            name="serve-pack-loop")
+                                            daemon=True, name=name)
             self._thread.start()
         return self
 

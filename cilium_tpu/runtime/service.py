@@ -25,6 +25,7 @@ MXU utilization.
 from __future__ import annotations
 
 import base64
+import itertools
 import json
 import os
 import socket
@@ -813,8 +814,6 @@ class VerdictService:
         end-of-stream. The chunked binary path shares the engine (and
         its auth staging) with every other verdict path — only the
         transport differs."""
-        from cilium_tpu.runtime.stream import StreamSession
-
         if self.loader.engine is None:
             send_msg(sock, {"error": "no policy loaded"})
             return
@@ -843,14 +842,27 @@ class VerdictService:
         if credit_window > 0:
             ack["credit"] = credit_window
         send_msg(sock, ack)
+        self._run_session(sock, req, credit_window)
+
+    def _run_session(self, sock: socket.socket, req: Dict,
+                     credit_window: int, **placement) -> None:
+        """One acknowledged stream's session, to end-of-stream, on this
+        service's loader and serve loop unless ``placement`` names
+        another (``loader``, ``serveloop``, ``router``, ``stream_id``,
+        ``lease``: a fleet replica's)."""
+        from cilium_tpu.runtime.stream import StreamSession
+
+        loader = placement.pop("loader", self.loader)
+        if "serveloop" not in placement:
+            placement["serveloop"] = self._ensure_serveloop()
         StreamSession(
-            self.loader, sock,
+            loader, sock,
             widths=req.get("widths") or None,
             authed_pairs_fn=self.bridge.authed_pairs_fn,
             pipeline_depth=int(req.get("pipeline_depth") or 8),
             verdictor=self.verdictor,
             credit_window=credit_window,
-            serveloop=self._ensure_serveloop(),
+            **placement,
         ).run()
 
     # -- request handling -------------------------------------------------
@@ -1102,10 +1114,10 @@ class VerdictService:
         timeout = getattr(self.admission_config, "drain_timeout_s",
                           30.0)
         flushed = self.bridge.batcher.drain(timeout=timeout)
-        if self.serveloop is not None:
-            # the ring drains too: pending packed chunks flush
-            # through the engine, leases release
-            flushed += self.serveloop.drain()
+        # the rings drain too: pending packed chunks flush through
+        # the engine, leases release
+        for loop in self._serve_loops():
+            flushed += loop.drain()
         warm = False
         if self.loader.revision > 0:
             warm = self.loader.snapshot_warm()
@@ -1137,10 +1149,86 @@ class VerdictService:
             self.bridge.batcher.drain(timeout=getattr(
                 self.admission_config, "drain_timeout_s", 30.0))
         self.bridge.batcher.close()
-        if self.serveloop is not None:
-            self.serveloop.stop()
+        for loop in self._serve_loops():
+            loop.stop()
         if os.path.exists(self.socket_path):
             os.unlink(self.socket_path)
+
+    def _serve_loops(self) -> List:
+        """The serve loops this service runs (none until a stream
+        builds one)."""
+        return [self.serveloop] if self.serveloop is not None else []
+
+
+class FleetVerdictService(VerdictService):
+    """:class:`VerdictService` over a fleet of in-process replicas, one
+    per loader (each loader staged on its own device): one socket, one
+    admission gate and one ``StreamSession`` per connection, as the
+    base. At ``stream_start`` the ``FleetRouter``
+    (runtime/fleetserve.py) places the stream by its rendezvous hash,
+    with spill headroom, on a replica and grants its lease there; the
+    session then runs over that replica's loader and ``ServeLoop``,
+    whose own pack thread answers every chunk of the stream. The
+    stream id is the hello's ``stream_id``, else ``conn-<n>`` by
+    connection order, so the same client order gives the same
+    placement. A stream the router sheds at placement runs by private
+    dispatch on the first replica's loader, as a ring-full shed does in
+    the base; the JSON ops answer from that loader too."""
+
+    def __init__(self, loaders: Sequence[Loader], socket_path: str,
+                 **kwargs):
+        from cilium_tpu.runtime.fleetserve import FleetRouter, HostReplica
+
+        super().__init__(loaders[0], socket_path, **kwargs)
+        fleet = self.loader.config.fleet
+        self.router = FleetRouter(
+            [HostReplica(i, ld) for i, ld in enumerate(loaders)],
+            heartbeat_interval_s=fleet.heartbeat_interval_s,
+            suspicion_ttl_s=fleet.suspicion_ttl_s,
+            spill_headroom=fleet.spill_headroom)
+        for r in self.router.replicas:
+            r.loop.authed_pairs_fn = self.bridge.authed_pairs_fn
+        self._streams = itertools.count()
+
+    def _run_session(self, sock: socket.socket, req: Dict,
+                     credit_window: int, **placement) -> None:
+        from cilium_tpu.runtime.serveloop import ShedError
+        from cilium_tpu.runtime.tracing import span
+
+        sid = str(req.get("stream_id") or f"conn-{next(self._streams)}")
+        with span("fleet.route"):
+            try:
+                host, lease = self.router.connect(sid)
+            except ShedError:
+                host, lease = None, None
+        if host is None:
+            super()._run_session(sock, req, credit_window, serveloop=None)
+            return
+        replica = self.router.replica_of(sid)
+        try:
+            super()._run_session(sock, req, credit_window,
+                                 loader=replica.loader,
+                                 serveloop=replica.loop,
+                                 router=self.router, stream_id=sid,
+                                 lease=lease)
+        finally:
+            self.router.release(sid)
+
+    def start(self) -> None:
+        self.router.start()
+        super().start()
+
+    def stop(self, drain: bool = True) -> None:
+        """``drain=True``: every replica's loop drains first (pending
+        chunks flush, every lease releases); then the base's stop,
+        which stops each pack thread."""
+        if drain:
+            for loop in self._serve_loops():
+                loop.drain()
+        super().stop(drain=drain)
+
+    def _serve_loops(self) -> List:
+        return [r.loop for r in self.router.replicas]
 
 
 class VerdictClient:

@@ -30,7 +30,8 @@ widths are fixed for the whole session (handshake), so the engine sees
 a handful of compiled shapes no matter what traffic streams.
 
 Protocol (after a ``{"op": "stream_start", ...}`` JSON handshake on
-the verdict socket; see ``VerdictService``):
+the verdict socket; see ``VerdictService``; an optional ``stream_id``
+in it is what a fleet front end places the stream by):
 
   frame   := <u32 payload_len> <u32 seq> <u8 kind> payload
   c→s     := kind 0: capture image | kind 1: end-of-stream (empty)
@@ -176,7 +177,8 @@ class StreamSession:
                  authed_pairs_fn=None,
                  pipeline_depth: int = PIPELINE_DEPTH,
                  verdictor=None, credit_window: int = 0,
-                 serveloop=None):
+                 serveloop=None, router=None,
+                 stream_id: Optional[str] = None, lease=None):
         from cilium_tpu.core.config import EngineConfig
 
         self.loader = loader
@@ -190,8 +192,12 @@ class StreamSession:
         #: a ring-full shed at lease time falls back to the private
         #: path for this session.
         self.serveloop = serveloop
-        self._lease = None
-        self._stream_id = f"stream-{id(self):x}"
+        #: a fleet front end's ``FleetRouter`` (runtime/fleetserve.py):
+        #: the stream was placed on ``serveloop``'s replica and granted
+        #: ``lease`` there; chunks and re-leases go through the router
+        self.router = router
+        self._lease = lease
+        self._stream_id = stream_id or f"stream-{id(self):x}"
         #: chunk credits advertised to this session's client in the
         #: stream_start ack; 0 = the client didn't opt in, grant
         #: nothing (old-peer interop)
@@ -348,26 +354,36 @@ class StreamSession:
             ShedError,
         )
 
-        loop = self.serveloop
         try:
             if self._lease is None:
-                self._lease = loop.connect(self._stream_id)
+                self._lease = self._connect(resume=False)
         except ShedError:
             self.serveloop = None
             return None
+        sections = (rec, l7, offsets, blob, gen)
         with TRACER.span("serve.submit", phase=PHASE_HOST,
                          records=len(rec)):
             try:
-                ticket = loop.submit(self._lease, rec, l7, offsets,
-                                     blob, gen=gen)
+                ticket = self._submit(sections)
             except LeaseExpired:
-                self._lease = loop.connect(self._stream_id,
-                                           resume=True)
-                ticket = loop.submit(self._lease, rec, l7, offsets,
-                                     blob, gen=gen)
+                self._lease = self._connect(resume=True)
+                ticket = self._submit(sections)
         with TRACER.span("serve.ticket.wait", phase=PHASE_DEVICE,
                          records=len(rec)):
             return ticket.wait(timeout=30.0)
+
+    def _connect(self, resume: bool):
+        """The stream's lease: from the router when one placed the
+        stream (it keeps the placement's books), else the loop's."""
+        if self.router is not None:
+            return self.router.connect(self._stream_id, resume=resume)[1]
+        return self.serveloop.connect(self._stream_id, resume=resume)
+
+    def _submit(self, sections):
+        if self.router is not None:
+            return self.router.submit(self._stream_id, self._lease,
+                                      sections)
+        return self.serveloop.submit(self._lease, *sections)
 
     def _oracle_chunk(self, rec, l7, offsets, blob, gen, pairs):
         """One chunk through the CPU oracle (the breaker's degraded
@@ -514,8 +530,12 @@ class StreamClient:
                  pipeline_depth: Optional[int] = None,
                  reconnect: bool = False, max_reconnects: int = 5,
                  backoff_base: float = 0.05, backoff_max: float = 2.0,
-                 reconnect_seed: int = 0):
+                 reconnect_seed: int = 0,
+                 stream_id: Optional[str] = None):
         self.socket_path = socket_path
+        #: rides the hello: a fleet front end places the stream by it
+        #: (a reconnect re-sends it, so the stream routes home)
+        self.stream_id = stream_id
         self.timeout = timeout
         self._widths = widths or {}
         self._pipeline_depth = pipeline_depth
@@ -557,6 +577,8 @@ class StreamClient:
                  "credit": True}
         if self._pipeline_depth:
             hello["pipeline_depth"] = int(self._pipeline_depth)
+        if self.stream_id:
+            hello["stream_id"] = self.stream_id
         send_msg(sock, hello)
         ack = recv_msg(sock)
         if not ack.get("ok"):

@@ -544,8 +544,9 @@ def _fleet_world(tmp_path, hosts=3, capacity=8, ttl=10.0):
     loader.regenerate(per_identity, revision=1)
     sections = capture_from_bytes(
         capture_to_bytes(scenario.flows[:16]))
+    cfg.serve.pack_interval_ms = 10.0
     replicas = [HostReplica(i, loader, capacity=capacity,
-                            lease_ttl_s=ttl, pack_interval_s=0.01)
+                            lease_ttl_s=ttl)
                 for i in range(hosts)]
     router = FleetRouter(replicas, heartbeat_interval_s=1.0,
                          suspicion_ttl_s=3.0, spill_headroom=0.0)

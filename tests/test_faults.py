@@ -1277,8 +1277,8 @@ def _fleet_world(tmp_path, hosts=3):
     cfg.loader.cache_dir = str(tmp_path / "cache")
     loader = Loader(cfg)
     loader.regenerate(per_identity, revision=1)
-    replicas = [HostReplica(i, loader, capacity=8, lease_ttl_s=60.0,
-                            pack_interval_s=0.01)
+    cfg.serve.pack_interval_ms = 10.0
+    replicas = [HostReplica(i, loader, capacity=8, lease_ttl_s=60.0)
                 for i in range(hosts)]
     router = FleetRouter(replicas, heartbeat_interval_s=1.0,
                          suspicion_ttl_s=3.0, spill_headroom=0.0)
